@@ -20,6 +20,7 @@ from repro.configs.dictlearn import (MOVIELENS, SYNTH_HETEROGENEOUS,
 from repro.core import compression
 from repro.core.variational import make_dictlearn
 from repro.data.synthetic import client_minibatch_fn
+from repro.launch.cache import enable_compile_cache
 
 import sys, os
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--participation", type=float, default=0.5)
     ap.add_argument("--skip-naive", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     exp = SETTINGS[args.setting]
     key = jax.random.PRNGKey(0)

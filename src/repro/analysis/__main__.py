@@ -21,7 +21,7 @@ import sys
 from .linter import SCHEMA_VERSION, lint_paths
 from .rules import RULES, rule_table
 
-DEFAULT_MAX_PRAGMAS = 4
+DEFAULT_MAX_PRAGMAS = 2
 
 
 def _baseline_counts(findings) -> dict:

@@ -34,8 +34,8 @@ _SHARD_MAP_RULE_PATCHED = False
 
 
 def _collapse_error_device_axis(error):
-    """Collapse the per-device leading axis the (jax 0.4.x) shard_map
-    checkify rule leaves on every error leaf: the rule expands each error
+    """Collapse the per-device leading axis jax's shard_map checkify rule
+    leaves on every error leaf: the rule expands each error
     value to shape (axis_size, ...) and never reduces it back, so the
     very next checked op after a shard_map dies in a select between the
     ambient scalar error and the (axis_size,)-shaped one. Reduce it here:
@@ -64,27 +64,21 @@ def _collapse_error_device_axis(error):
 def _patch_shard_map_checkify_rule():
     """Make checkify compose with shard_map on this jax version.
 
-    jax 0.4.37's ``shard_map_error_check`` returns the error with a
-    leading device axis (it lax.expand_dims's every error leaf and shards
-    the output over the whole mesh) — correct inside the shard_map, but
-    the interpreter threads that shaped error on as the ambient state and
-    the next join fails with "select cases must have the same shapes".
-    Wrap the registered rule to collapse the device axis on the way out.
-    Idempotent; a no-op if the rule is absent or a future jax fixed it
-    (scalar error leaves pass through untouched)."""
+    jax 0.9's ``shard_map_error_check`` (``jax._src.checkify``) returns
+    the error with a leading device axis (it lax.expand_dims's every
+    error leaf and shards the output over the whole mesh) — correct
+    inside the shard_map, but the interpreter threads that shaped error
+    on as the ambient state and the next join (a select, or a loop carry
+    such as the driver's fixed-order client reduce) fails with "select
+    cases must have the same shapes". Wrap the registered rule to
+    collapse the device axis on the way out. Idempotent; scalar error
+    leaves pass through untouched."""
     global _SHARD_MAP_RULE_PATCHED
     if _SHARD_MAP_RULE_PATCHED:
         return
-    try:
-        import jax._src.checkify as cki
-        from jax.experimental import shard_map as _sm
-        orig = cki.error_checks.get(_sm.shard_map_p)
-    except (ImportError, AttributeError):   # layout moved: nothing to fix
-        _SHARD_MAP_RULE_PATCHED = True
-        return
-    if orig is None:
-        _SHARD_MAP_RULE_PATCHED = True
-        return
+    import jax._src.checkify as cki
+    from jax._src import shard_map as _sm
+    orig = cki.error_checks[_sm.shard_map_p]
 
     def rule_with_scalar_error(error, enabled_errors, *vals, **params):
         new_error, outs = orig(error, enabled_errors, *vals, **params)

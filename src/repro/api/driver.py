@@ -22,10 +22,10 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
-from ..core.compression import _tree_bytes, verify_payload, zero_invalid_rows
+from ..core.compression import (_tree_bytes, verify_payload, weighted_sum,
+                                zero_invalid_rows)
 from ..core.surrogate import (tree_lerp, tree_scale, tree_sub, tree_sq_norm,
                               tree_sq_norm_ew)
 from .problem import MMProblem, as_problem
@@ -191,10 +191,10 @@ def _variate_update(v, q, coef):
 
 
 def _weighted_reduce(w, q):
-    """The mu-weighted client reduction (line 13), dtype-preserving: a
-    tensordot against f32 weights would silently upcast bf16 leaves."""
-    return jax.tree.map(
-        lambda x: jnp.tensordot(w, x, axes=1).astype(x.dtype), q)
+    """The mu-weighted client reduction (line 13), dtype-preserving: the
+    fixed-order sum accumulates in f32 (``weighted_sum``) and casts back
+    ONCE, so bf16 leaves are not silently upcast."""
+    return jax.tree.map(lambda x: weighted_sum(w, x).astype(x.dtype), q)
 
 
 # a private fold_in lane for the per-round tier-boundary keys: deriving
@@ -327,9 +327,13 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                 f"divide evenly over the '{client_axis}' mesh axis "
                 f"(size {mesh.shape[client_axis]})")
 
-    def client_update(batch, v_c, qkey):
+    def client_update(batch, v_c, qkey, view, x_ref):
         """One client's round: oracle (+ optional metrics), drift, wire
-        encode. Returns (payload, per-client metrics dict)."""
+        encode. Returns (payload, per-client metrics dict). The server
+        state comes in as arguments, never by closure: a shard_map body
+        that closes over a committed, mesh-sharded array fails to
+        differentiate on jax 0.9 (its zeros carry the Auto mesh into the
+        Manual one)."""
         if problem.s_bar_metrics is not None:
             s_i, cm = problem.s_bar_metrics(batch, view)   # line 6 (oracle)
         else:
@@ -345,8 +349,9 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
             return comp.encode(qkey, d), cm                # line 9: wire fmt
         return comp.apply(qkey, d), cm                     # line 9 (A4)
 
-    def upd(batch, v_c, qkey):
-        return client_update(batch, v_c if use_v else None, qkey)
+    def upd(batch, v_c, qkey, view, x_ref):
+        return client_update(batch, v_c if use_v else None, qkey, view,
+                             x_ref)
 
     def _mask_q(x, m):
         # dtype-preserving: never let an f32 mask upcast a bf16 payload
@@ -373,7 +378,7 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
         # (flat), or edge-wise in the f32 accumulation dtype (two-tier —
         # the tier boundary does the ONE downcast)
         def body_core(agg_sum, cb, v_c, qk, mu_c, m_c, cf, e_c=None):
-            payload_c, cm = upd(cb, v_c, qk)
+            payload_c, cm = upd(cb, v_c, qk, view, x_ref)
             surv_c = m_c
             if verify:
                 payload_c, ok = _checked(
@@ -449,8 +454,9 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
         ek_specs = (PartitionSpec(topo.edge_axis),) if reenc else ()
         measured = {}
 
-        def stage_local(cb, vi, qk, mu_l, m_l, cf_l):
-            payload_l, cm = jax.vmap(upd, in_axes=(0, 0, 0))(cb, vi, qk)
+        def stage_local(view_l, xr_l, cb, vi, qk, mu_l, m_l, cf_l):
+            payload_l, cm = jax.vmap(upd, in_axes=(0, 0, 0, None, None))(
+                cb, vi, qk, view_l, xr_l)
             n_l = m_l.shape[0]
             m_eff = m_l
             if verify:
@@ -477,7 +483,7 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                 q_l = jax.tree.map(msk, q_l)
                 vi_new = _variate_update(vi, q_l, alpha / p)
                 part = jax.tree.map(
-                    lambda x: jnp.tensordot(mu_l, x, axes=1), q_l)
+                    lambda x: weighted_sum(mu_l, x), q_l)
             else:
                 vi_new = ()
                 if use_wire and comp.decode_reduce is not None:
@@ -495,7 +501,7 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                     q_l = (jax.tree.map(msk, comp.decode(payload_l))
                            if use_wire else jax.tree.map(msk, payload_l))
                     part = jax.tree.map(
-                        lambda x: jnp.tensordot(mu_l, x, axes=1), q_l)
+                        lambda x: weighted_sum(mu_l, x), q_l)
             # the ACTUAL per-device psum operand (static under jit): the
             # model-shaped partial aggregate — what really crosses the
             # mesh, measured here rather than modeled
@@ -510,9 +516,9 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
         ns_axes = ((client_axis, topo.edge_axis) if two_tier
                    else client_axis)
 
-        def client_stage(cb, vi, qk, mu_l, m_l, cf_l, *ek):
+        def client_stage(view_l, xr_l, cb, vi, qk, mu_l, m_l, cf_l, *ek):
             part, vi_new, cm, ns_l = stage_local(
-                cb, vi, qk, mu_l, m_l,
+                view_l, xr_l, cb, vi, qk, mu_l, m_l,
                 cf_l if verify and corrupt is not None else None)
             # the within-edge (flat: cross-mesh) reduce, in the
             # accumulation dtype
@@ -537,12 +543,12 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                   else jnp.float32(0.0))
             return agg_l, vi_new, cm, ns
 
-        agg, v_i_new, cmetrics, n_survive = shard_map(
+        agg, v_i_new, cmetrics, n_survive = jax.shard_map(
             client_stage, mesh=mesh,
-            in_specs=(cspec,) * 6 + ek_specs,
+            in_specs=(PartitionSpec(),) * 2 + (cspec,) * 6 + ek_specs,
             out_specs=(PartitionSpec(), cspec, cspec, PartitionSpec()),
-            check_rep=False)(client_batches, v_i, quant_keys, mu, mask,
-                             cflags, *ek_args)
+            check_vma=False)(view, x_ref, client_batches, v_i, quant_keys,
+                             mu, mask, cflags, *ek_args)
         if not verify:
             n_survive = jnp.sum(mask)
         # the ONE downcast back to the iterate dtype, AFTER the collective
@@ -567,28 +573,33 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                      else client_axis)
             cspec = PartitionSpec(gaxes)
 
-            def client_stage(cb, vi, qk):
+            def client_stage(view_l, xr_l, cb, vi, qk):
                 # each device slice runs its local clients...
-                local = jax.vmap(upd, in_axes=(0, 0, 0))(cb, vi, qk)
+                local = jax.vmap(upd, in_axes=(0, 0, 0, None, None))(
+                    cb, vi, qk, view_l, xr_l)
                 # ...and the uplink collective moves the ENCODED buffers:
                 # packed codes + per-group scales cross the mesh boundary
                 return jax.tree.map(
                     lambda x: jax.lax.all_gather(x, gaxes, axis=0,
                                                  tiled=True), local)
 
-            # check_rep=False: all_gather's replication over client_axis is
-            # real but not statically inferred on this jax version
-            payload, cmetrics = shard_map(
+            # check_vma=False: no varying-axis type checks in the body;
+            # the tiled all_gather makes every output replicated over
+            # client_axis by construction
+            payload, cmetrics = jax.shard_map(
                 client_stage, mesh=mesh,
-                in_specs=(cspec, cspec, cspec), out_specs=PartitionSpec(),
-                check_rep=False)(client_batches, v_i, quant_keys)
+                in_specs=(PartitionSpec(),) * 2 + (cspec,) * 3,
+                out_specs=PartitionSpec(),
+                check_vma=False)(view, x_ref, client_batches, v_i,
+                                 quant_keys)
             # the gathered stack's actual buffer bytes (static under jit):
             # for wire compressors this is n * payload_bytes — asserted in
             # tests/test_sharded_driver.py, not just logged
             collective_bytes = float(_tree_bytes(payload))
         else:
-            payload, cmetrics = jax.vmap(upd, in_axes=(0, 0, 0))(
-                client_batches, v_i, quant_keys)
+            payload, cmetrics = jax.vmap(
+                upd, in_axes=(0, 0, 0, None, None))(
+                client_batches, v_i, quant_keys, view, x_ref)
         n_survive = jnp.sum(mask)
         if use_wire:
             # actual uplink bytes of ONE client's payload, read off the

@@ -779,6 +779,20 @@ def decode_tree(payload):
     return jax.tree.map(decode_leaf, payload, is_leaf=_is_payload_leaf)
 
 
+def weighted_sum(w, x):
+    """``sum_c w[c] * x[c]`` over the leading client axis, accumulated in
+    the promoted dtype (f32 under f32 weights) in a FIXED sequential
+    order: a loop carry the compiler cannot reassociate. A ``tensordot``
+    leaves the order to XLA, which picks it per fusion context — XLA:CPU
+    sums a stack fused with the vmapped encode in another order than the
+    same stack out of a shard_map all_gather, a 1-ulp split between the
+    mesh and single-device trajectories. The sequential order is also the
+    Pallas ``decode_reduce`` kernel's (client grid axis innermost)."""
+    def body(c, acc):
+        return acc + w[c] * x[c]
+    return jax.lax.fori_loop(1, x.shape[0], body, w[0] * x[0])
+
+
 def decode_reduce_leaf(p, w, kernel_threshold: int = KERNEL_DISPATCH_MIN,
                        fused: Optional[bool] = None):
     """Weighted reduction over the leading client axis of ONE stacked
@@ -795,9 +809,8 @@ def decode_reduce_leaf(p, w, kernel_threshold: int = KERNEL_DISPATCH_MIN,
     materializes; nibble-packed codes unpack to int8 first (1 byte/coord,
     still never the 4-byte f32 stack). Everything else — small/misaligned
     leaves and raw passthrough leaves — decodes via the jnp oracle and
-    reduces with a plain tensordot (bit-identical to decode-then-reduce).
-    The kernel accumulates sequentially in c, so against the tensordot
-    order it agrees to f32 rounding, not bit-for-bit.
+    reduces with ``weighted_sum`` (bit-identical to decode-then-reduce).
+    The kernel accumulates sequentially in c, the same order.
 
     ``fused`` routes the kernel dispatch the same way ``_kernel_route``
     does for apply/encode (the PR-4 lesson: guard per leaf, not by
@@ -809,7 +822,7 @@ def decode_reduce_leaf(p, w, kernel_threshold: int = KERNEL_DISPATCH_MIN,
     is already in a per-device (manual / shard_map) context — the
     driver's reduce uplink; ``False`` forces the jnp path."""
     if not isinstance(p, PackedLeaf):
-        return jnp.tensordot(w, p, axes=1)
+        return weighted_sum(w, p)
     shape, g, bits = p.shape, p.group, p.bits
     n = int(math.prod(shape))
     C = w.shape[0]
@@ -852,7 +865,7 @@ def decode_reduce_leaf(p, w, kernel_threshold: int = KERNEL_DISPATCH_MIN,
         if p.mode == "flat":
             out = out.reshape(-1)[:n]
         return out.reshape(shape)
-    return jnp.tensordot(w, decode_leaf(p), axes=1)
+    return weighted_sum(w, decode_leaf(p))
 
 
 def decode_reduce_tree(payload, w,

@@ -1,9 +1,8 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On this CPU container the kernels execute in interpret mode (the kernel body
-runs in Python for validation); on TPU pass ``interpret=False`` (or set
-``repro.kernels.ops.INTERPRET = False`` at process start) for the compiled
-Mosaic kernels.
+``INTERPRET`` follows the default backend at import: on a CPU backend the
+kernels execute in interpret mode (the kernel body runs in Python for
+validation); on TPU they compile to Mosaic kernels.
 """
 from __future__ import annotations
 
@@ -11,7 +10,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from .quantize_block import (decode_reduce_grouped_pallas,
@@ -121,8 +119,8 @@ def quantize_dequantize_sharded(x, u, bits: int, group: int,
                                           bits=bits, group=group)
         return out.reshape(xb.shape)
 
-    return shard_map(body, mesh=sharding.mesh, in_specs=(pspec, pspec),
-                     out_specs=pspec, check_rep=False)(x, u)
+    return jax.shard_map(body, mesh=sharding.mesh, in_specs=(pspec, pspec),
+                         out_specs=pspec, check_vma=False)(x, u)
 
 
 def quantize_encode_sharded(x, u, bits: int, group: int,
@@ -141,8 +139,8 @@ def quantize_encode_sharded(x, u, bits: int, group: int,
         return (codes.reshape(xb.shape),
                 scales.reshape(xb.shape[:-1] + (-1,)))
 
-    return shard_map(body, mesh=sharding.mesh, in_specs=(pspec, pspec),
-                     out_specs=(pspec, pspec), check_rep=False)(x, u)
+    return jax.shard_map(body, mesh=sharding.mesh, in_specs=(pspec, pspec),
+                         out_specs=(pspec, pspec), check_vma=False)(x, u)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "group"))
@@ -153,8 +151,8 @@ def dequantize_reduce_grouped(codes, scales, w, bits: int = 8,
     ``sum_c w[c] * dequant(codes[c], scales[c])`` without materializing the
     decoded f32 client stack. codes: (C, R, D) int8 with D % group == 0;
     scales: (C, R, D // group) f32; w: (C,) f32. Dequant math is the exact
-    tail of ``ref.decode_groups_ref``; the c-sequential accumulation
-    matches a tensordot over the decoded stack to f32 rounding."""
+    tail of ``ref.decode_groups_ref``; the c-sequential accumulation is
+    the order of ``core.compression.weighted_sum``."""
     return decode_reduce_grouped_pallas(codes, scales, w, bits=bits,
                                         group=group, interpret=INTERPRET)
 

@@ -190,16 +190,12 @@ def compile_one(arch_id: str, shape_name: str, multi_pod: bool,
             args = (params_sds, cache_sds, tok_sds, pos_sds)
             extra = {}
 
-        # jax.set_mesh is the newer-jax spelling; on older releases the Mesh
-        # context manager provides the same ambient mesh (shardings here are
-        # explicit NamedShardings, so the context only scopes the lowering).
-        set_mesh = getattr(jax, "set_mesh", None)
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        # shardings here are explicit NamedShardings, so the ambient mesh
+        # only scopes the lowering
+        with jax.set_mesh(mesh):
             lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
             compiled = lowered.compile()
             cost = compiled.cost_analysis() or {}
-            if isinstance(cost, (list, tuple)):   # older jax: one dict/program
-                cost = cost[0] if cost else {}
             mem = compiled.memory_analysis()
             hlo = compiled.as_text()
         acct = hlo_accounting(hlo)

@@ -23,13 +23,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
     devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    # jax.sharding.AxisType only exists on newer jax; Auto is the default
-    # axis type there, so omitting it is equivalent on older releases.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.sharding.Mesh(devices, axes)
     return jax.sharding.Mesh(
-        devices, axes, axis_types=(axis_type.Auto,) * len(axes))
+        devices, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def client_axes(multi_pod: bool):
@@ -70,11 +66,8 @@ def make_edge_mesh(n_edges: int, clients_per_edge: int = None, *,
             f"needs {n} devices, have {len(devices)}")
     grid = np.asarray(devices[:n]).reshape(n_edges, clients_per_edge)
     axes = (edge_axis, client_axis)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.sharding.Mesh(grid, axes)
     return jax.sharding.Mesh(
-        grid, axes, axis_types=(axis_type.Auto,) * len(axes))
+        grid, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def cohort_capacity(mesh, client_axis="clients", per_device: int = 1) -> int:

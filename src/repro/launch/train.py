@@ -13,14 +13,17 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import NamedTuple
 
 import jax
+import numpy as np
 
 import repro.configs as C
 from repro.data.synthetic import token_stream
 from repro.fed import trainer as FT
 from repro.models.model import build_model
 from repro.checkpoint import checkpoint as ckpt
+from repro.launch.cache import enable_compile_cache
 
 
 def preset_config(cfg, preset: str):
@@ -40,7 +43,15 @@ def preset_config(cfg, preset: str):
     raise ValueError(preset)
 
 
-def main():
+class TrainResult(NamedTuple):
+    state: FT.FedLMState
+    losses: list           # per-step all-client mean loss (host floats)
+    compiled: object       # the compiled train step the loop ran
+    compile_seconds: float
+    step_seconds: float    # mean wall seconds per step, compile excluded
+
+
+def main(argv=None) -> TrainResult:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-medium-14b", choices=C.ARCH_IDS)
     ap.add_argument("--preset", default="smoke",
@@ -56,7 +67,8 @@ def main():
     ap.add_argument("--rho", type=float, default=0.05)
     ap.add_argument("--checkpoint", default=None)
     ap.add_argument("--log-every", type=int, default=10)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = preset_config(C.get(args.arch), args.preset)
     model = build_model(cfg)
@@ -71,7 +83,6 @@ def main():
           f"clients={args.clients} p={args.participation} "
           f"quant={args.quant_bits}b")
 
-    step_fn = jax.jit(FT.make_train_step(model, fcfg))
     b_local = args.batch // args.clients
 
     # heterogeneous client token streams (non-IID unigram skew)
@@ -91,18 +102,35 @@ def main():
                      cfg.d_model)) * 0.02
         return batch
 
+    def gamma_at(t):
+        return np.float32(args.gamma / (1.0 + t) ** 0.5)
+
+    key, kb, ks = jax.random.split(key, 3)
+    batch = sample_batch(kb)
+    lowered = jax.jit(FT.make_train_step(model, fcfg)).lower(
+        state, batch, ks, gamma_at(0))
+    t0 = time.time()
+    compiled = lowered.compile()   # reads the persistent cache when placed
+    compile_seconds = time.time() - t0
+
+    losses = []
     t0 = time.time()
     for t in range(args.steps):
-        key, kb, ks = jax.random.split(key, 3)
-        gamma = args.gamma / (1.0 + t) ** 0.5
-        state, m = step_fn(state, sample_batch(kb), ks, gamma)
+        if t:
+            key, kb, ks = jax.random.split(key, 3)
+            batch = sample_batch(kb)
+        state, m = compiled(state, batch, ks, gamma_at(t))
+        losses.append(float(m["loss"]))
         if t % args.log_every == 0 or t == args.steps - 1:
-            print(f"step {t:5d}  loss={float(m['loss']):.4f} "
+            print(f"step {t:5d}  loss={losses[-1]:.4f} "
                   f"e_s={float(m['e_s']):.3e}  active={int(m['n_active'])} "
                   f"({time.time() - t0:.1f}s)", flush=True)
+    step_seconds = (time.time() - t0) / max(args.steps, 1)
     if args.checkpoint:
         ckpt.save(args.checkpoint, state.s_hat)
         print(f"saved mirror parameter to {args.checkpoint}")
+    return TrainResult(state, losses, compiled, compile_seconds,
+                       step_seconds)
 
 
 if __name__ == "__main__":

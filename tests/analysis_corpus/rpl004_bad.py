@@ -1,7 +1,6 @@
 """RPL004 firing: downcast inside a shard_map body BEFORE the psum."""
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 
@@ -10,9 +9,9 @@ def partial_reduce(mesh, x):
         part = xl.sum(axis=0).astype(jnp.bfloat16)  # expect: RPL004
         return jax.lax.psum(part, "clients")
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(PartitionSpec("clients"),),
-                     out_specs=PartitionSpec())(x)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(PartitionSpec("clients"),),
+                         out_specs=PartitionSpec())(x)
 
 
 def partial_reduce_same_line(mesh, x):
@@ -21,6 +20,6 @@ def partial_reduce_same_line(mesh, x):
         # most direct form of the PR-5 bug, on ONE line
         return jax.lax.psum(xl.sum(0).astype(jnp.bfloat16), "clients")  # expect: RPL004
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(PartitionSpec("clients"),),
-                     out_specs=PartitionSpec())(x)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(PartitionSpec("clients"),),
+                         out_specs=PartitionSpec())(x)

@@ -2,7 +2,6 @@
 the collective (the PR-5 invariant)."""
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 
@@ -12,9 +11,9 @@ def partial_reduce(mesh, x):
         agg = jax.lax.psum(part, "clients")
         return agg.astype(jnp.bfloat16)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(PartitionSpec("clients"),),
-                     out_specs=PartitionSpec())(x)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(PartitionSpec("clients"),),
+                         out_specs=PartitionSpec())(x)
 
 
 def host_cast(x):
@@ -29,6 +28,6 @@ def partial_reduce_one_line(mesh, x):
         # is neither at a later position nor an ancestor of the astype
         return jax.lax.psum(xl.sum(axis=0), "clients").astype(jnp.bfloat16)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(PartitionSpec("clients"),),
-                     out_specs=PartitionSpec())(x)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(PartitionSpec("clients"),),
+                         out_specs=PartitionSpec())(x)

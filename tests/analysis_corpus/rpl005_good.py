@@ -1,6 +1,5 @@
 """RPL005 non-firing: collectives inside shard_map / pmap bodies."""
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec
 
 
@@ -8,9 +7,9 @@ def aggregate(mesh, x):
     def body(xl):
         return jax.lax.psum(xl, "clients")
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(PartitionSpec("clients"),),
-                     out_specs=PartitionSpec())(x)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(PartitionSpec("clients"),),
+                         out_specs=PartitionSpec())(x)
 
 
 def mean_over_devices(x):
@@ -29,6 +28,6 @@ def two_tier_aggregate(mesh, x):
         total = jax.lax.psum(part, ("edge", "client"))  # both tiers
         return total
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=(PartitionSpec(("edge", "client")),),
-                     out_specs=PartitionSpec())(x)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(PartitionSpec(("edge", "client")),),
+                         out_specs=PartitionSpec())(x)

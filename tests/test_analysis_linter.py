@@ -165,7 +165,7 @@ def test_cli_fails_on_bad_corpus_file():
 
 
 def test_cli_pragma_budget_enforced():
-    # budget 0 makes the real tree's 4 pragmas a failure in --strict mode
+    # budget 0 makes the real tree's 2 pragmas a failure in --strict mode
     r = _run_cli("src/repro", "--strict", "--max-pragmas", "0")
     assert r.returncode == 1
     assert "allow-pragma" in r.stdout + r.stderr

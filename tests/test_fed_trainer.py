@@ -279,3 +279,57 @@ def test_t_map_is_l2_prox():
     out = FT.T_map(s, fcfg)
     np.testing.assert_allclose(np.asarray(out["w"]),
                                np.ones(3) / (1 + 0.1 * 0.5), rtol=1e-6)
+
+
+_SUBPROCESS_PHYSICAL_TWO_STEPS = r"""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import Mesh
+import repro.configs as C
+from repro.fed import trainer as FT
+from repro.models.model import build_model, make_batch
+
+assert jax.device_count() == 4, jax.device_count()
+KEY = jax.random.PRNGKey(0)
+cfg = C.get("whisper-base").reduced()
+model = build_model(cfg)
+n = 4
+b = make_batch(KEY, cfg, batch_size=n, seq_len=16)
+batch = {k: v.reshape((n, 1) + v.shape[1:]) for k, v in b.items()}
+mesh = Mesh(np.asarray(jax.devices()), ("clients",))
+losses = {}
+for mode, kw in (("logical", {}), ("physical", dict(mesh=mesh,
+                                                    uplink="reduce"))):
+    fcfg = FT.FedLMConfig(n_clients=n, rho=0.05, client_mode=mode)
+    state = FT.init_state(model, KEY, fcfg)
+    step = jax.jit(FT.make_train_step(model, fcfg, **kw))
+    out = []
+    for t in range(2):       # step 2 takes the committed, sharded state
+        state, m = step(state, batch, jax.random.PRNGKey(t), 0.5)
+        out.append(float(m["loss"]))
+    losses[mode] = out
+np.testing.assert_allclose(losses["physical"], losses["logical"],
+                           rtol=1e-5)
+print("OK-PHYSICAL")
+"""
+
+
+def test_physical_mesh_trains_on_committed_state_under_forced_4_devices():
+    """One silo per device, uplink='reduce': the second round takes the
+    first round's mesh-sharded state. The shard_map body must get the
+    server state as an argument — closing over it failed to
+    differentiate the model's scanned loss on jax 0.9."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src"),
+         env.get("PYTHONPATH", "")])
+    out = subprocess.run([sys.executable, "-c",
+                          _SUBPROCESS_PHYSICAL_TWO_STEPS],
+                         capture_output=True, text=True, env=env,
+                         timeout=600)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "OK-PHYSICAL" in out.stdout

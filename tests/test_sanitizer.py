@@ -179,7 +179,6 @@ def test_collapse_failure_degrades_to_upstream_rule(monkeypatch):
     — if a jax upgrade reshuffles that layout, the patched shard_map rule
     must degrade to the upstream rule's error, not crash the trace with
     the collapse's own exception."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     from repro.analysis import runtime
@@ -192,9 +191,9 @@ def test_collapse_failure_degrades_to_upstream_rule(monkeypatch):
     x = jnp.ones((len(jax.devices()), 4))
 
     def f(a):
-        return shard_map(lambda xl: jnp.log(xl),
-                         mesh=mesh, in_specs=(PartitionSpec("clients"),),
-                         out_specs=PartitionSpec("clients"))(a)
+        return jax.shard_map(lambda xl: jnp.log(xl),
+                             mesh=mesh, in_specs=(PartitionSpec("clients"),),
+                             out_specs=PartitionSpec("clients"))(a)
 
     err, out = runtime.checkified(f)(x)  # must not raise the RuntimeError
     err.throw()  # log(1) trips nothing
