@@ -17,6 +17,7 @@ match the old loops operation for operation —
 """
 from __future__ import annotations
 
+import contextlib
 import warnings
 from typing import Any, NamedTuple, Optional
 
@@ -30,6 +31,7 @@ from ..core.surrogate import (tree_lerp, tree_scale, tree_sub, tree_sq_norm,
                               tree_sq_norm_ew)
 from .problem import MMProblem, as_problem
 from .schedule import resolve_schedule, schedule_length
+from .spans import span
 from .spec import FederationSpec, participation_draw
 
 Pytree = Any
@@ -334,20 +336,22 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
         that closes over a committed, mesh-sharded array fails to
         differentiate on jax 0.9 (its zeros carry the Auto mesh into the
         Manual one)."""
-        if problem.s_bar_metrics is not None:
-            s_i, cm = problem.s_bar_metrics(batch, view)   # line 6 (oracle)
-        else:
-            s_i, cm = problem.s_bar(batch, view), {}
-        out = problem.T(s_i) if param_space else s_i       # eq. 21 local MM
+        with jax.named_scope("fedmm.client_oracle"):
+            if problem.s_bar_metrics is not None:
+                s_i, cm = problem.s_bar_metrics(batch, view)   # line 6
+            else:
+                s_i, cm = problem.s_bar(batch, view), {}
+            out = problem.T(s_i) if param_space else s_i   # eq. 21 local MM
         if spec.delta == "oracle":
             d = out                                        # raw payload
         else:
             d = tree_sub(out, x_ref)                       # line 7 (drift)
             if use_v:
                 d = tree_sub(d, v_c)
-        if use_wire:
-            return comp.encode(qkey, d), cm                # line 9: wire fmt
-        return comp.apply(qkey, d), cm                     # line 9 (A4)
+        with jax.named_scope("fedmm.wire_encode"):
+            if use_wire:
+                return comp.encode(qkey, d), cm            # line 9: wire fmt
+            return comp.apply(qkey, d), cm                 # line 9 (A4)
 
     def upd(batch, v_c, qkey, view, x_ref):
         return client_update(batch, v_c if use_v else None, qkey, view,
@@ -380,23 +384,25 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
         def body_core(agg_sum, cb, v_c, qk, mu_c, m_c, cf, e_c=None):
             payload_c, cm = upd(cb, v_c, qk, view, x_ref)
             surv_c = m_c
-            if verify:
-                payload_c, ok = _checked(
-                    payload_c, cf if corrupt is not None else None)
-                surv_c = m_c * ok.astype(m_c.dtype)
-            q_c = comp.decode(payload_c) if use_wire else payload_c
-            q_c = jax.tree.map(lambda x: _mask_q(x, m_c), q_c)
-            v_c_new = (_variate_update(v_c, q_c, alpha / p)
-                       if use_v else ())
-            if two_tier:
-                agg_sum = jax.tree.map(
-                    lambda a, x: a.at[e_c].add(mu_c
-                                               * x.astype(jnp.float32)),
-                    agg_sum, q_c)
-            else:
-                agg_sum = jax.tree.map(
-                    lambda a, x: a + (mu_c * x).astype(a.dtype),
-                    agg_sum, q_c)
+            with jax.named_scope("fedmm.wire_decode"):
+                if verify:
+                    payload_c, ok = _checked(
+                        payload_c, cf if corrupt is not None else None)
+                    surv_c = m_c * ok.astype(m_c.dtype)
+                q_c = comp.decode(payload_c) if use_wire else payload_c
+            with jax.named_scope("fedmm.aggregate"):
+                q_c = jax.tree.map(lambda x: _mask_q(x, m_c), q_c)
+                v_c_new = (_variate_update(v_c, q_c, alpha / p)
+                           if use_v else ())
+                if two_tier:
+                    agg_sum = jax.tree.map(
+                        lambda a, x: a.at[e_c].add(
+                            mu_c * x.astype(jnp.float32)),
+                        agg_sum, q_c)
+                else:
+                    agg_sum = jax.tree.map(
+                        lambda a, x: a + (mu_c * x).astype(a.dtype),
+                        agg_sum, q_c)
             return agg_sum, v_c_new, cm, surv_c
         if two_tier:
             zeros = jax.tree.map(
@@ -428,7 +434,9 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                 (client_batches, v_i, quant_keys, mu, mask) + eids)
             n_survive = jnp.sum(mask)
         if two_tier and tier_finalize:
-            agg, backbone_bytes = tier_boundary(spec, agg, edge_keys, x_ref)
+            with jax.named_scope("fedmm.aggregate"):
+                agg, backbone_bytes = tier_boundary(spec, agg, edge_keys,
+                                                    x_ref)
         # static per-client wire bytes via eval_shape (no stacked payload
         # exists on this path)
         wire_bytes_client = comp.wire_bytes(x_ref) if use_wire else None
@@ -464,7 +472,8 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                 # clients' payloads; zeroed rows reduce to exact zeros on
                 # every path below, so only the survivor COUNT needs an
                 # extra collective
-                payload_l, ok_l = _checked(payload_l, cf_l)
+                with jax.named_scope("fedmm.wire_decode"):
+                    payload_l, ok_l = _checked(payload_l, cf_l)
                 m_eff = m_l * ok_l.astype(m_l.dtype)
 
             def msk(x):
@@ -476,30 +485,28 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
             # them would lose bf16-epsilon per round — the gather path
             # does one f32 tensordot over all n clients and casts once,
             # and the reduce path must match that discipline
-            if use_v:
-                # the variates need the decoded local stack anyway
-                # (O(n/axis_size * model) — still never the full n)
-                q_l = comp.decode(payload_l) if use_wire else payload_l
-                q_l = jax.tree.map(msk, q_l)
-                vi_new = _variate_update(vi, q_l, alpha / p)
-                part = jax.tree.map(
-                    lambda x: weighted_sum(mu_l, x), q_l)
-            else:
+            if use_wire and comp.decode_reduce is not None and not use_v:
+                # fold the mask into the weights (exact: the mask is
+                # 0.0/1.0) and fuse dequantize into the accumulation via
+                # the COMPRESSOR's own reduce (which carries its kernel
+                # dispatch policy) — the decoded local f32 stack never
+                # materializes. fused=True: this IS a per-device
+                # shard_map body.
                 vi_new = ()
-                if use_wire and comp.decode_reduce is not None:
-                    # fold the mask into the weights (exact: the mask is
-                    # 0.0/1.0) and fuse dequantize into the accumulation
-                    # via the COMPRESSOR's own reduce (which carries its
-                    # kernel dispatch policy) — the decoded local f32
-                    # stack never materializes. fused=True: this IS a
-                    # per-device shard_map body.
+                with jax.named_scope("fedmm.wire_decode"):
                     part = comp.decode_reduce(payload_l, mu_l * m_l,
                                               fused=True)
-                else:
-                    # wire compressors without a fused reduce decode
-                    # first; raw payloads reduce directly
-                    q_l = (jax.tree.map(msk, comp.decode(payload_l))
-                           if use_wire else jax.tree.map(msk, payload_l))
+            else:
+                # the variates need the decoded local stack anyway
+                # (O(n/axis_size * model) — still never the full n);
+                # wire compressors without a fused reduce decode first;
+                # raw payloads reduce directly
+                with jax.named_scope("fedmm.wire_decode"):
+                    q_l = comp.decode(payload_l) if use_wire else payload_l
+                with jax.named_scope("fedmm.aggregate"):
+                    q_l = jax.tree.map(msk, q_l)
+                    vi_new = (_variate_update(vi, q_l, alpha / p) if use_v
+                              else ())
                     part = jax.tree.map(
                         lambda x: weighted_sum(mu_l, x), q_l)
             # the ACTUAL per-device psum operand (static under jit): the
@@ -520,27 +527,28 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
             part, vi_new, cm, ns_l = stage_local(
                 view_l, xr_l, cb, vi, qk, mu_l, m_l,
                 cf_l if verify and corrupt is not None else None)
-            # the within-edge (flat: cross-mesh) reduce, in the
-            # accumulation dtype
-            agg_l = jax.tree.map(lambda x: jax.lax.psum(x, client_axis),
-                                 part)
-            if two_tier:
-                if reenc:
-                    # tier boundary: requantize THIS edge's partial with
-                    # its per-tier key (fresh digests re-stamped) and
-                    # measure what actually crosses the backbone — then
-                    # decode back to the f32 accumulation dtype for the
-                    # cross-edge psum
-                    pay_e = comp.reencode(ek[0][0], agg_l)
-                    measured["backbone_edge_bytes"] = _tree_bytes(pay_e)
-                    agg_l = comp.decode(pay_e)
-                else:
-                    measured["backbone_edge_bytes"] = _tree_bytes(agg_l)
-                # ONE cross-edge psum crosses the backbone
+            with jax.named_scope("fedmm.aggregate"):
+                # the within-edge (flat: cross-mesh) reduce, in the
+                # accumulation dtype
                 agg_l = jax.tree.map(
-                    lambda x: jax.lax.psum(x, topo.edge_axis), agg_l)
-            ns = (jax.lax.psum(ns_l, ns_axes) if verify
-                  else jnp.float32(0.0))
+                    lambda x: jax.lax.psum(x, client_axis), part)
+                if two_tier:
+                    if reenc:
+                        # tier boundary: requantize THIS edge's partial
+                        # with its per-tier key (fresh digests re-stamped)
+                        # and measure what actually crosses the backbone
+                        # — then decode back to the f32 accumulation
+                        # dtype for the cross-edge psum
+                        pay_e = comp.reencode(ek[0][0], agg_l)
+                        measured["backbone_edge_bytes"] = _tree_bytes(pay_e)
+                        agg_l = comp.decode(pay_e)
+                    else:
+                        measured["backbone_edge_bytes"] = _tree_bytes(agg_l)
+                    # ONE cross-edge psum crosses the backbone
+                    agg_l = jax.tree.map(
+                        lambda x: jax.lax.psum(x, topo.edge_axis), agg_l)
+                ns = (jax.lax.psum(ns_l, ns_axes) if verify
+                      else jnp.float32(0.0))
             return agg_l, vi_new, cm, ns
 
         agg, v_i_new, cmetrics, n_survive = jax.shard_map(
@@ -552,7 +560,8 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
         if not verify:
             n_survive = jnp.sum(mask)
         # the ONE downcast back to the iterate dtype, AFTER the collective
-        agg = jax.tree.map(lambda a, x: a.astype(x.dtype), agg, x_ref)
+        with jax.named_scope("fedmm.aggregate"):
+            agg = jax.tree.map(lambda a, x: a.astype(x.dtype), agg, x_ref)
         collective_bytes = float(measured["psum_operand_bytes"])
         if two_tier:
             # total backbone traffic: every edge's tier-boundary buffer
@@ -579,9 +588,10 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                     cb, vi, qk, view_l, xr_l)
                 # ...and the uplink collective moves the ENCODED buffers:
                 # packed codes + per-group scales cross the mesh boundary
-                return jax.tree.map(
-                    lambda x: jax.lax.all_gather(x, gaxes, axis=0,
-                                                 tiled=True), local)
+                with jax.named_scope("fedmm.aggregate"):
+                    return jax.tree.map(
+                        lambda x: jax.lax.all_gather(x, gaxes, axis=0,
+                                                     tiled=True), local)
 
             # check_vma=False: no varying-axis type checks in the body;
             # the tiled all_gather makes every output replicated over
@@ -605,36 +615,39 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
             # actual uplink bytes of ONE client's payload, read off the
             # stacked encoded buffers (shapes are static under jit)
             wire_bytes_client = comp.encoded_bytes(payload) / n_local
-            if verify:
-                # server-side verification of the (gathered) stack; a
-                # failing client degrades the round exactly like an
-                # equivalent participation draw that excluded it
-                payload, ok = _checked(payload, corrupt)
-                n_survive = jnp.sum(mask * ok.astype(mask.dtype))
-            q = comp.decode(payload)   # batched; fuses into the aggregation
+            with jax.named_scope("fedmm.wire_decode"):
+                if verify:
+                    # server-side verification of the (gathered) stack; a
+                    # failing client degrades the round exactly like an
+                    # equivalent participation draw that excluded it
+                    payload, ok = _checked(payload, corrupt)
+                    n_survive = jnp.sum(mask * ok.astype(mask.dtype))
+                q = comp.decode(payload)   # batched; fuses into the reduce
         else:
             wire_bytes_client = None
             q = payload
-        # non-participating clients send nothing / keep V_i
-        q = jax.tree.map(
-            lambda x: _mask_q(x, mask.reshape((n_local,)
-                                              + (1,) * (x.ndim - 1))),
-            q)
+        with jax.named_scope("fedmm.aggregate"):
+            # non-participating clients send nothing / keep V_i
+            q = jax.tree.map(
+                lambda x: _mask_q(x, mask.reshape((n_local,)
+                                                  + (1,) * (x.ndim - 1))),
+                q)
 
-        # client control variates (lines 8/11) + server aggregation (13)
-        v_i_new = _variate_update(v_i, q, alpha / p) if use_v else ()
-        if two_tier:
-            # within-edge tier: per-edge f32 partials by the stable
-            # assignment (q is already masked; mu carries the weights)
-            parts = _edge_partials(q, mu, jnp.asarray(edge_ids, jnp.int32),
-                                   topo.n_edges)
-            if tier_finalize:
-                agg, backbone_bytes = tier_boundary(spec, parts, edge_keys,
-                                                    x_ref)
+            # client control variates (lines 8/11) + aggregation (13)
+            v_i_new = _variate_update(v_i, q, alpha / p) if use_v else ()
+            if two_tier:
+                # within-edge tier: per-edge f32 partials by the stable
+                # assignment (q is already masked; mu carries the weights)
+                parts = _edge_partials(q, mu,
+                                       jnp.asarray(edge_ids, jnp.int32),
+                                       topo.n_edges)
+                if tier_finalize:
+                    agg, backbone_bytes = tier_boundary(spec, parts,
+                                                        edge_keys, x_ref)
+                else:
+                    agg = parts
             else:
-                agg = parts
-        else:
-            agg = _weighted_reduce(mu, q)
+                agg = _weighted_reduce(mu, q)
     return (agg, v_i_new, cmetrics, wire_bytes_client, collective_bytes,
             n_survive, backbone_bytes)
 
@@ -650,59 +663,63 @@ def _server_apply(problem: MMProblem, spec: FederationSpec,
     a (staleness-weighted) sum of ``CohortPartial.agg`` terms.
 
     Returns ``(new_state, h, aux_metrics)``."""
-    n, p, alpha = spec.n_clients, spec.participation, spec.alpha
-    param_space = spec.aggregation == "parameter"
-    use_v = spec.use_variates
-    if spec.normalization == "realized":
-        scale = n / jnp.maximum(n_active, 1.0)
-        h = jax.tree.map(lambda a: (scale * a).astype(a.dtype), agg)
-    else:
-        h = tree_scale(agg, 1.0 / p)
-    if use_v:
-        h = jax.tree.map(lambda v, hh: v + hh.astype(v.dtype), state.v, h)
+    with jax.named_scope("fedmm.server"):
+        n, p, alpha = spec.n_clients, spec.participation, spec.alpha
+        param_space = spec.aggregation == "parameter"
+        use_v = spec.use_variates
+        if spec.normalization == "realized":
+            scale = n / jnp.maximum(n_active, 1.0)
+            h = jax.tree.map(lambda a: (scale * a).astype(a.dtype), agg)
+        else:
+            h = tree_scale(agg, 1.0 / p)
+        if use_v:
+            h = jax.tree.map(lambda v, hh: v + hh.astype(v.dtype), state.v, h)
 
-    # server update (lines 15-16): SA step + projection, unless the problem
-    # supplies its own server optimizer (e.g. FedAdam) or the spec asks
-    # for FedAvgM heavy-ball momentum on the aggregated direction
-    if problem.server_opt is not None:
-        if spec.server_momentum > 0.0:
-            raise ValueError(
-                "server_momentum and a custom MMProblem.server_opt both "
-                "claim the server update — fold the momentum into your "
-                "server_opt instead")
-        x_new, opt_new = problem.server_opt(state.x, h, gamma, state.opt)
-    elif spec.server_momentum > 0.0:
-        # m <- beta m + h (buffer keeps the iterate dtype), x <- x + gamma m
-        opt_new = jax.tree.map(
-            lambda m, hh: (spec.server_momentum * m
-                           + hh.astype(m.dtype)).astype(m.dtype),
-            state.opt, h)
-        x_new = jax.tree.map(
-            lambda mm, xx: (gamma * mm.astype(xx.dtype) + xx).astype(xx.dtype),
-            opt_new, state.x)
-        if not param_space:
-            x_new = problem.project(x_new)
-    else:
-        x_new = jax.tree.map(
-            lambda hh, xx: (gamma * hh.astype(xx.dtype) + xx).astype(xx.dtype),
-            h, state.x)
-        if not param_space:
-            x_new = problem.project(x_new)
-        opt_new = state.opt
+        # server update (lines 15-16): SA step + projection, unless the problem
+        # supplies its own server optimizer (e.g. FedAdam) or the spec asks
+        # for FedAvgM heavy-ball momentum on the aggregated direction
+        if problem.server_opt is not None:
+            if spec.server_momentum > 0.0:
+                raise ValueError(
+                    "server_momentum and a custom MMProblem.server_opt both "
+                    "claim the server update — fold the momentum into your "
+                    "server_opt instead")
+            x_new, opt_new = problem.server_opt(state.x, h, gamma, state.opt)
+        elif spec.server_momentum > 0.0:
+            # m <- beta m + h (buffer keeps the iterate dtype),
+            # x <- x + gamma m
+            opt_new = jax.tree.map(
+                lambda m, hh: (spec.server_momentum * m
+                               + hh.astype(m.dtype)).astype(m.dtype),
+                state.opt, h)
+            x_new = jax.tree.map(
+                lambda mm, xx: (gamma * mm.astype(xx.dtype)
+                                + xx).astype(xx.dtype),
+                opt_new, state.x)
+            if not param_space:
+                x_new = problem.project(x_new)
+        else:
+            x_new = jax.tree.map(
+                lambda hh, xx: (gamma * hh.astype(xx.dtype)
+                                + xx).astype(xx.dtype),
+                h, state.x)
+            if not param_space:
+                x_new = problem.project(x_new)
+            opt_new = state.opt
 
-    # server control variate (line 17)
-    v_new = (jax.tree.map(
-        lambda v, a: v + ((alpha / p) * a).astype(v.dtype), state.v, agg)
-        if use_v else ())
+        # server control variate (line 17)
+        v_new = (jax.tree.map(
+            lambda v, a: v + ((alpha / p) * a).astype(v.dtype), state.v, agg)
+            if use_v else ())
 
-    # problem-owned server state (FedMM-OT line 16: conjugate update)
-    if problem.server_step is not None:
-        aux_new, aux_metrics = problem.server_step(state.aux, x_new)
-    else:
-        aux_new, aux_metrics = state.aux, {}
-    new_state = DriverState(x=x_new, v=v_new, v_i=v_i_new, aux=aux_new,
-                            opt=opt_new, step=state.step + 1)
-    return new_state, h, aux_metrics
+        # problem-owned server state (FedMM-OT line 16: conjugate update)
+        if problem.server_step is not None:
+            aux_new, aux_metrics = problem.server_step(state.aux, x_new)
+        else:
+            aux_new, aux_metrics = state.aux, {}
+        new_state = DriverState(x=x_new, v=v_new, v_i=v_i_new, aux=aux_new,
+                                opt=opt_new, step=state.step + 1)
+        return new_state, h, aux_metrics
 
 
 def _broadcast_view(problem: MMProblem, spec: FederationSpec,
@@ -710,11 +727,12 @@ def _broadcast_view(problem: MMProblem, spec: FederationSpec,
     """Line 4: the view broadcast to clients — the mirror image T(Shat)
     (surrogate mode), the iterate itself (parameter mode), or the
     problem's custom view hook."""
-    if spec.aggregation == "parameter":
-        return state.x
-    if problem.view is not None:
-        return problem.view(state.x, state.aux)
-    return problem.T(state.x)
+    with jax.named_scope("fedmm.view"):
+        if spec.aggregation == "parameter":
+            return state.x
+        if problem.view is not None:
+            return problem.view(state.x, state.aux)
+        return problem.T(state.x)
 
 
 def centralized_step(problem: MMProblem, state: DriverState, batch, gamma):
@@ -890,21 +908,23 @@ def step(problem: MMProblem, spec: FederationSpec, state: DriverState,
 
     view = _broadcast_view(problem, spec, state)           # line 4
 
-    drawn, quant_keys = participation_draw(key, spec)      # A5
-    if active is None:
-        active = drawn
-    corrupt = None
-    if spec.faults is not None and spec.faults.any_injection:
-        # fault-private fold_in lanes off the round key — the A5/A4 draws
-        # above are untouched, so a zero-probability FaultSpec leaves the
-        # trajectory bit-identical to faults=None
-        drop, corr = spec.faults.client_draw(key, n)
-        # a dropped client's uplink never arrives: fold it into the A5
-        # mask so mu renormalizes per spec.normalization (no bytes billed)
-        active = jnp.logical_and(jnp.asarray(active).astype(jnp.bool_),
-                                 jnp.logical_not(drop))
-        corrupt = corr if spec.faults.corrupt > 0.0 else None
-    mask = active.astype(jnp.float32)
+    with jax.named_scope("fedmm.participation"):
+        drawn, quant_keys = participation_draw(key, spec)  # A5
+        if active is None:
+            active = drawn
+        corrupt = None
+        if spec.faults is not None and spec.faults.any_injection:
+            # fault-private fold_in lanes off the round key — the A5/A4
+            # draws above are untouched, so a zero-probability FaultSpec
+            # leaves the trajectory bit-identical to faults=None
+            drop, corr = spec.faults.client_draw(key, n)
+            # a dropped client's uplink never arrives: fold it into the
+            # A5 mask so mu renormalizes per spec.normalization (no bytes
+            # billed)
+            active = jnp.logical_and(jnp.asarray(active).astype(jnp.bool_),
+                                     jnp.logical_not(drop))
+            corrupt = corr if spec.faults.corrupt > 0.0 else None
+        mask = active.astype(jnp.float32)
 
     (agg, v_i_new, cmetrics, wire_bytes_client, collective_bytes,
      n_survive, backbone_bytes) \
@@ -1205,31 +1225,17 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
     """
     problem = as_problem(problem)
 
-    if sanitize and spec is None:
-        raise ValueError("sanitize=True is the federated driver's runtime "
-                         "sanitizer; the centralized path does not thread "
-                         "it — wrap centralized_step in "
-                         "analysis.runtime.checkified yourself")
-    if audit_keys and spec is None:
-        raise ValueError("audit_keys=True audits the federated driver's "
-                         "host key chain; the centralized path draws no "
-                         "keys — activate a keytrace.KeyAudit yourself if "
-                         "your batch pipeline consumes them")
-    if audit_keys:
-        from ..analysis.keytrace import resolve_audit
-        audit = resolve_audit(audit_keys)
-        with audit.activate():
-            return run(problem, x0, data, schedule, spec=spec, key=key,
-                       n_rounds=n_rounds, eval_batch=eval_batch,
-                       eval_every=eval_every, track_mirror=track_mirror,
-                       diag=diag, scan=scan, v0_i=v0_i,
-                       init_batches=init_batches, state0=state0,
-                       scan_batch_bytes_max=scan_batch_bytes_max,
-                       mesh=mesh, client_axis=client_axis,
-                       client_mode=client_mode, uplink=uplink,
-                       sanitize=sanitize)
-
     if spec is None:
+        if sanitize:
+            raise ValueError("sanitize=True is the federated driver's "
+                             "runtime sanitizer; the centralized path does "
+                             "not thread it — wrap centralized_step in "
+                             "analysis.runtime.checkified yourself")
+        if audit_keys:
+            raise ValueError("audit_keys=True audits the federated driver's "
+                             "host key chain; the centralized path draws "
+                             "no keys — activate a keytrace.KeyAudit "
+                             "yourself if your batch pipeline consumes them")
         return _run_centralized(problem, x0, data, schedule,
                                 n_rounds=n_rounds, scan=scan,
                                 state0=state0)
@@ -1240,19 +1246,43 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
         n_rounds = schedule_length(schedule)
         if n_rounds is None:
             raise ValueError("n_rounds required with a callable schedule")
-    gammas = resolve_schedule(schedule, n_rounds)
+    audit = contextlib.nullcontext()
+    if audit_keys:
+        from ..analysis.keytrace import resolve_audit
+        audit = resolve_audit(audit_keys).activate()
+    with audit, span("run", rounds=n_rounds, clients=spec.n_clients):
+        return _run_federated(
+            problem, x0, data, schedule, spec, key, n_rounds,
+            eval_batch=eval_batch, eval_every=eval_every,
+            track_mirror=track_mirror, diag=diag, scan=scan, v0_i=v0_i,
+            init_batches=init_batches, state0=state0,
+            scan_batch_bytes_max=scan_batch_bytes_max, mesh=mesh,
+            client_axis=client_axis, client_mode=client_mode,
+            uplink=uplink, sanitize=sanitize)
+
+
+def _run_federated(problem, x0, data, schedule, spec, key, n_rounds, *,
+                   eval_batch, eval_every, track_mirror, diag, scan, v0_i,
+                   init_batches, state0, scan_batch_bytes_max, mesh,
+                   client_axis, client_mode, uplink, sanitize):
+    """``run``'s federated body, inside its ``fedmm.run`` span: the host
+    phases (key chain, schedule, batch draws, stacking, the scan) each
+    in a child span."""
+    with span("run.schedule"):
+        gammas = resolve_schedule(schedule, n_rounds)
     param_space = spec.aggregation == "parameter"
     track_mirror = track_mirror and not param_space
 
     # host-side key chain — replicates the legacy run loops exactly:
     # each round consumes (k_round, k_batch) off the same chain
-    round_keys, batch_keys = [], []
+    with span("run.keys"):
+        round_keys, batch_keys = [], []
+        for t in range(n_rounds):
+            key, k_round, k_batch = jax.random.split(key, 3)
+            round_keys.append(k_round)
+            batch_keys.append(k_batch)
+        round_keys = jnp.stack(round_keys)
     static = not callable(data)
-    for t in range(n_rounds):
-        key, k_round, k_batch = jax.random.split(key, 3)
-        round_keys.append(k_round)
-        batch_keys.append(k_batch)
-    round_keys = jnp.stack(round_keys)
     lazy = False
     budget = (SCAN_BATCH_BYTES_MAX if scan_batch_bytes_max is None
               else scan_batch_bytes_max)
@@ -1265,12 +1295,14 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
         # trajectory — each round's batch is generated lazily below
         lazy, batches = True, None
     else:
-        first = data(0, batch_keys[0])
-        if not check_disabled:
-            round_bytes = _tree_bytes(first)
-            over = n_rounds * round_bytes > budget
-        else:
-            over = False           # budget disabled: skip the measurement
+        with span("run.batches"):
+            first = data(0, batch_keys[0])
+            # a disabled budget skips the measurement
+            round_bytes = None if check_disabled else _tree_bytes(first)
+            over = round_bytes is not None and n_rounds * round_bytes > budget
+            if not over:
+                batch_list = [first] + [data(t, batch_keys[t])
+                                        for t in range(1, n_rounds)]
         if over:
             # do NOT materialize the trajectory: generate each round's
             # batch inside the loop, constant-memory like the legacy loops
@@ -1293,9 +1325,8 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
             scan = False
             lazy, batches, first = True, None, None
         else:
-            batch_list = [first] + [data(t, batch_keys[t])
-                                    for t in range(1, n_rounds)]
-            batches = _stack_batches(batch_list)
+            with span("run.stack"):
+                batches = _stack_batches(batch_list)
             del batch_list, first   # the stack is the only resident copy
 
     if state0 is None:
@@ -1331,13 +1362,14 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
                 theta_eval = state.x if param_space else problem.T(state.x)
                 return jnp.asarray(problem.loss(eval_batch, theta_eval),
                                    jnp.float32)
-            if eval_every > 1:
-                do = (((t_idx + 1) % eval_every == 0)
-                      | (t_idx == n_rounds - 1))
-                m["loss"] = jax.lax.cond(
-                    do, eval_loss, lambda _: jnp.float32(jnp.nan), None)
-            else:
-                m["loss"] = eval_loss(None)
+            with jax.named_scope("fedmm.eval"):
+                if eval_every > 1:
+                    do = (((t_idx + 1) % eval_every == 0)
+                          | (t_idx == n_rounds - 1))
+                    m["loss"] = jax.lax.cond(
+                        do, eval_loss, lambda _: jnp.float32(jnp.nan), None)
+                else:
+                    m["loss"] = eval_loss(None)
         return m, theta_new, diag_new
 
     theta_prev0 = problem.T(state0.x) if track_mirror else ()
@@ -1366,18 +1398,20 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
         t_idxs = jnp.arange(n_rounds)
         xs = ((gammas, round_keys, t_idxs) if static
               else (gammas, round_keys, t_idxs, batches))
-        if sanitize:
-            # ONE checkify around the whole scanned trajectory: the checks
-            # ride the scan body's trace, so err carries the first tripped
-            # check of ANY round; thrown eagerly here, after the scan
-            from ..analysis.runtime import checkified
-            err, ((state, _, _), hist) = checkified(
-                lambda c0, x: jax.lax.scan(body, c0, x))(
-                    (state0, theta_prev0, diag_prev0), xs)
-            err.throw()
-        else:
-            (state, _, _), hist = jax.lax.scan(
-                body, (state0, theta_prev0, diag_prev0), xs)
+        with span("run.scan"):
+            if sanitize:
+                # ONE checkify around the whole scanned trajectory: the
+                # checks ride the scan body's trace, so err carries the
+                # first tripped check of ANY round; thrown eagerly here,
+                # after the scan
+                from ..analysis.runtime import checkified
+                err, ((state, _, _), hist) = checkified(
+                    lambda c0, x: jax.lax.scan(body, c0, x))(
+                        (state0, theta_prev0, diag_prev0), xs)
+                err.throw()
+            else:
+                (state, _, _), hist = jax.lax.scan(
+                    body, (state0, theta_prev0, diag_prev0), xs)
         return state, hist
 
     # python fallback: identical math, one jitted step per round
