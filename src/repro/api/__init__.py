@@ -12,5 +12,5 @@ from .schedule import (decaying_stepsize, resolve_schedule,  # noqa: F401
                        schedule_length)
 from .driver import (CohortPartial, CohortSlice, DriverState,  # noqa: F401
                      apply_partial, centralized_init, centralized_step,
-                     history_list, init, mean_oracle_diag, run, step,
-                     variates_at_init)
+                     clear_trajectory_cache, history_list, init,
+                     mean_oracle_diag, run, step, variates_at_init)

@@ -18,6 +18,7 @@ match the old loops operation for operation —
 from __future__ import annotations
 
 import contextlib
+import functools
 import warnings
 from typing import Any, NamedTuple, Optional
 
@@ -52,6 +53,16 @@ UPLINKS = ("gather", "reduce")
 # dedupe set without bound for the life of the process.
 _SCAN_FALLBACK_WARNED: "dict" = {}
 _SCAN_FALLBACK_WARNED_MAX = 128
+
+
+def _lru_put(cache: dict, key, value, max_size: int):
+    """Insert or refresh ``key`` as the most recently used entry of an
+    insertion-ordered dict, then drop the least recently used entries
+    beyond ``max_size``."""
+    cache.pop(key, None)
+    cache[key] = value
+    while len(cache) > max_size:
+        del cache[next(iter(cache))]
 
 
 class DriverState(NamedTuple):
@@ -1155,6 +1166,161 @@ def _stack_batches(batch_list):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *batch_list)
 
 
+class _Trajectory(NamedTuple):
+    """Everything that decides the traced program of a federated
+    ``run(scan=True)``: the static half of its cache key. The arrays
+    (initial state, schedule, round keys, batches, eval batch) are the
+    program's arguments."""
+    problem: MMProblem
+    spec: FederationSpec
+    n_rounds: int
+    mesh: Any
+    client_axis: str
+    client_mode: str
+    uplink: str
+    sanitize: bool
+    track_mirror: bool      # already False under parameter aggregation
+    diag: Any               # (name, fn) or None
+    eval_every: int
+    static: bool            # one batch pytree reused every round
+
+
+class _ByIdentity:
+    """Cache-key stand-in for an unhashable static argument (e.g. a
+    ``FederationSpec`` with ``mu`` set): equal only to itself, and it
+    holds the object so its id is not reused while the entry lives."""
+    __slots__ = ("obj",)
+
+    def __init__(self, obj):
+        self.obj = obj
+
+    def __hash__(self):
+        return id(self.obj)
+
+    def __eq__(self, other):
+        return isinstance(other, _ByIdentity) and other.obj is self.obj
+
+
+def _hashable(obj):
+    try:
+        hash(obj)
+    except TypeError:
+        return _ByIdentity(obj)
+    return obj
+
+
+# compiled trajectories, least recently used first: (static config,
+# argument structure and avals) -> jitted function. An entry keeps its
+# problem, spec and whatever their hooks close over alive; the program
+# of the benchmark's MovieLens run holds 5.9 MB of code on a v5e
+_TRAJECTORIES: "dict" = {}
+_TRAJECTORIES_MAX = 16
+
+
+def clear_trajectory_cache():
+    """Drop every kept trajectory program of ``run``, and with them the
+    problems, specs and closed-over arrays their keys hold."""
+    _TRAJECTORIES.clear()
+
+
+def _trajectory_program(cfg: _Trajectory, args):
+    """The jitted trajectory for ``cfg`` and the shapes of ``args``, built
+    on a miss and kept in a bounded LRU (``_TRAJECTORIES_MAX`` entries),
+    so a repeated ``run`` dispatches without tracing, lowering or a
+    compile-cache load. Hashable static parts key by value (a rebuilt
+    equal ``as_problem(sur)`` hits), unhashable ones by identity."""
+    leaves, tree = jax.tree.flatten(args)
+    key = (tuple(_hashable(f) for f in cfg), tree,
+           tuple(jax.typeof(x) for x in leaves))
+    program = _TRAJECTORIES.get(key)
+    if program is None:
+        fn = functools.partial(_trajectory, cfg)
+        if cfg.sanitize:
+            from ..analysis.runtime import checkified
+            fn = checkified(fn)
+        program = jax.jit(fn)
+    _lru_put(_TRAJECTORIES, key, program, _TRAJECTORIES_MAX)
+    return program
+
+
+def _trajectory(cfg: _Trajectory, state0, theta_prev0, diag_prev0, gammas,
+                round_keys, batches, eval_batch):
+    """The whole federated trajectory as one ``lax.scan``; traced only on
+    a miss of ``_trajectory_program``."""
+    jax.monitoring.record_event("/fedmm/run/trajectory/trace")
+    problem, spec = cfg.problem, cfg.spec
+    has_diag = cfg.diag is not None
+
+    def body(carry, xs):
+        state, theta_prev, diag_prev = carry
+        if cfg.static:
+            gamma, k, t_idx = xs
+            batch = batches
+        else:
+            gamma, k, t_idx, batch = xs
+        state, m = step(problem, spec, state, batch, gamma, k,
+                        mesh=cfg.mesh, client_axis=cfg.client_axis,
+                        client_mode=cfg.client_mode, uplink=cfg.uplink,
+                        _comm_audit=cfg.sanitize)
+        m, theta_new, diag_new = _round_metrics(cfg, eval_batch, state, m,
+                                                gamma, theta_prev, diag_prev,
+                                                t_idx)
+        carry = (state,
+                 theta_new if cfg.track_mirror else (),
+                 diag_new if has_diag else ())
+        return carry, m
+
+    t_idxs = jnp.arange(cfg.n_rounds)
+    xs = ((gammas, round_keys, t_idxs) if cfg.static
+          else (gammas, round_keys, t_idxs, batches))
+    (state, _, _), hist = jax.lax.scan(
+        body, (state0, theta_prev0, diag_prev0), xs)
+    return state, hist
+
+
+def _round_metrics(cfg: _Trajectory, eval_batch, state, m, gamma,
+                   theta_prev, diag_prev, t_idx):
+    """Post-step diagnostics; returns (m, theta_new, diag_new)."""
+    problem = cfg.problem
+    theta_new = diag_new = None
+    if cfg.track_mirror:
+        theta_new = problem.T(state.x)
+        m["e_p_s"] = (tree_sq_norm(tree_sub(theta_new, theta_prev))
+                      / gamma ** 2)
+    if cfg.diag is not None:
+        diag_name, diag_fn = cfg.diag
+        diag_new = diag_fn(state.x)
+        m[diag_name] = (tree_sq_norm(tree_sub(diag_new, diag_prev))
+                        / gamma ** 2)
+    if problem.loss is not None and eval_batch is not None:
+        if "loss" in m:
+            raise ValueError(
+                "metric key collision: the problem's s_bar_metrics "
+                "already reports a per-client 'loss' and the eval hook "
+                "would overwrite it — drop eval_batch or rename the "
+                "client metric")
+        param_space = cfg.spec.aggregation == "parameter"
+
+        # ONE f32 code path for both cadences: the eval_every == 1
+        # branch used to record problem.loss in native dtype (and
+        # compute theta_eval a second time) while the lax.cond branch
+        # cast to f32 — the stacked metric would silently change dtype
+        # with the cadence
+        def eval_loss(_):
+            theta_eval = state.x if param_space else problem.T(state.x)
+            return jnp.asarray(problem.loss(eval_batch, theta_eval),
+                               jnp.float32)
+        with jax.named_scope("fedmm.eval"):
+            if cfg.eval_every > 1:
+                do = (((t_idx + 1) % cfg.eval_every == 0)
+                      | (t_idx == cfg.n_rounds - 1))
+                m["loss"] = jax.lax.cond(
+                    do, eval_loss, lambda _: jnp.float32(jnp.nan), None)
+            else:
+                m["loss"] = eval_loss(None)
+    return m, theta_new, diag_new
+
+
 def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
         key=None, n_rounds: Optional[int] = None, eval_batch=None,
         eval_every: int = 1, track_mirror: bool = False, diag=None,
@@ -1190,7 +1356,21 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
     falls back to a per-round python loop (same math, useful when stacked
     batches would not fit or for debugging). With ``scan=False`` the
     trajectory batches are never stacked OR measured — each round's batch
-    is generated lazily.
+    is generated lazily. The federated scan is one ``jax.jit`` program
+    kept across calls, keyed by what decides its trace (problem, spec,
+    ``n_rounds``, mesh and client knobs, ``sanitize``, ``track_mirror``,
+    ``diag``, ``eval_every``, static or per-round data) and by the
+    structure and shapes of its arrays; a repeat call only dispatches it.
+    At most ``_TRAJECTORIES_MAX`` (16) programs are kept, least recently
+    used dropped first, each with the problem, spec and closed-over
+    arrays of its key; ``clear_trajectory_cache()`` drops them. Hashable
+    problems and specs key by value, unhashable ones (a spec with ``mu``
+    set) by identity. A miss traces, lowers and loads the program once,
+    the work an eager scan does every call; on a TPU v5e host a miss has
+    read up to 0.2 s slower than the eager scan, and a process's first
+    call up to 0.8 s slower (see the package README). As with any ``jax.jit``, the problem's hooks
+    must be pure: Python state they read at trace time is frozen into the
+    first trace of that key.
     scan_batch_bytes_max: device-byte budget for the stacked trajectory
     batches; above it the scan falls back to the lazy per-round loop
     (warning fired once per distinct situation, with the measured bytes).
@@ -1270,8 +1450,7 @@ def _run_federated(problem, x0, data, schedule, spec, key, n_rounds, *,
     in a child span."""
     with span("run.schedule"):
         gammas = resolve_schedule(schedule, n_rounds)
-    param_space = spec.aggregation == "parameter"
-    track_mirror = track_mirror and not param_space
+    track_mirror = track_mirror and spec.aggregation != "parameter"
 
     # host-side key chain — replicates the legacy run loops exactly:
     # each round consumes (k_round, k_batch) off the same chain
@@ -1307,14 +1486,10 @@ def _run_federated(problem, x0, data, schedule, spec, key, n_rounds, *,
             # do NOT materialize the trajectory: generate each round's
             # batch inside the loop, constant-memory like the legacy loops
             sig = (round_bytes, n_rounds, budget)
-            if sig in _SCAN_FALLBACK_WARNED:
-                # LRU refresh: re-insert so hot situations outlive cold ones
-                _SCAN_FALLBACK_WARNED[sig] = _SCAN_FALLBACK_WARNED.pop(sig)
-            else:
-                _SCAN_FALLBACK_WARNED[sig] = True
-                while len(_SCAN_FALLBACK_WARNED) > _SCAN_FALLBACK_WARNED_MAX:
-                    oldest = next(iter(_SCAN_FALLBACK_WARNED))
-                    del _SCAN_FALLBACK_WARNED[oldest]
+            warned = sig in _SCAN_FALLBACK_WARNED
+            _lru_put(_SCAN_FALLBACK_WARNED, sig, True,
+                     _SCAN_FALLBACK_WARNED_MAX)
+            if not warned:
                 warnings.warn(
                     f"stacked batches would exceed the scan budget "
                     f"({round_bytes:,} bytes/round x {n_rounds} rounds = "
@@ -1333,85 +1508,27 @@ def _run_federated(problem, x0, data, schedule, spec, key, n_rounds, *,
         state0 = init(problem, x0, spec, v0_i=v0_i,
                       init_batches=init_batches)
 
-    diag_name, diag_fn = diag if diag is not None else (None, None)
-
-    def round_metrics(state, m, gamma, theta_prev, diag_prev, t_idx):
-        """Post-step diagnostics; returns (m, theta_new, diag_new)."""
-        theta_new = diag_new = None
-        if track_mirror:
-            theta_new = problem.T(state.x)
-            m["e_p_s"] = (tree_sq_norm(tree_sub(theta_new, theta_prev))
-                          / gamma ** 2)
-        if diag_fn is not None:
-            diag_new = diag_fn(state.x)
-            m[diag_name] = (tree_sq_norm(tree_sub(diag_new, diag_prev))
-                            / gamma ** 2)
-        if problem.loss is not None and eval_batch is not None:
-            if "loss" in m:
-                raise ValueError(
-                    "metric key collision: the problem's s_bar_metrics "
-                    "already reports a per-client 'loss' and the eval hook "
-                    "would overwrite it — drop eval_batch or rename the "
-                    "client metric")
-            # ONE f32 code path for both cadences: the eval_every == 1
-            # branch used to record problem.loss in native dtype (and
-            # compute theta_eval a second time) while the lax.cond branch
-            # cast to f32 — the stacked metric would silently change dtype
-            # with the cadence
-            def eval_loss(_):
-                theta_eval = state.x if param_space else problem.T(state.x)
-                return jnp.asarray(problem.loss(eval_batch, theta_eval),
-                                   jnp.float32)
-            with jax.named_scope("fedmm.eval"):
-                if eval_every > 1:
-                    do = (((t_idx + 1) % eval_every == 0)
-                          | (t_idx == n_rounds - 1))
-                    m["loss"] = jax.lax.cond(
-                        do, eval_loss, lambda _: jnp.float32(jnp.nan), None)
-                else:
-                    m["loss"] = eval_loss(None)
-        return m, theta_new, diag_new
-
+    cfg = _Trajectory(problem, spec, n_rounds, mesh, client_axis,
+                      client_mode, uplink, sanitize, track_mirror, diag,
+                      eval_every, static)
+    diag_fn = diag[1] if diag is not None else None
     theta_prev0 = problem.T(state0.x) if track_mirror else ()
     diag_prev0 = diag_fn(state0.x) if diag_fn is not None else ()
 
     if scan:
-        def body(carry, xs):
-            state, theta_prev, diag_prev = carry
-            if static:
-                gamma, k, t_idx = xs
-                batch = batches
-            else:
-                gamma, k, t_idx, batch = xs
-            state, m = step(problem, spec, state, batch, gamma, k,
-                            mesh=mesh, client_axis=client_axis,
-                            client_mode=client_mode, uplink=uplink,
-                            _comm_audit=sanitize)
-            m, theta_new, diag_new = round_metrics(state, m, gamma,
-                                                   theta_prev, diag_prev,
-                                                   t_idx)
-            carry = (state,
-                     theta_new if track_mirror else (),
-                     diag_new if diag_fn is not None else ())
-            return carry, m
-
-        t_idxs = jnp.arange(n_rounds)
-        xs = ((gammas, round_keys, t_idxs) if static
-              else (gammas, round_keys, t_idxs, batches))
+        jax.monitoring.record_event("/fedmm/run/trajectory/call")
+        args = (state0, theta_prev0, diag_prev0, gammas, round_keys,
+                batches, eval_batch)
         with span("run.scan"):
+            program = _trajectory_program(cfg, args)
             if sanitize:
-                # ONE checkify around the whole scanned trajectory: the
-                # checks ride the scan body's trace, so err carries the
-                # first tripped check of ANY round; thrown eagerly here,
-                # after the scan
-                from ..analysis.runtime import checkified
-                err, ((state, _, _), hist) = checkified(
-                    lambda c0, x: jax.lax.scan(body, c0, x))(
-                        (state0, theta_prev0, diag_prev0), xs)
+                # the checks ride the scan body's trace, so err carries
+                # the first tripped check of ANY round; thrown eagerly
+                # here, after the scan
+                err, (state, hist) = program(*args)
                 err.throw()
             else:
-                (state, _, _), hist = jax.lax.scan(
-                    body, (state0, theta_prev0, diag_prev0), xs)
+                state, hist = program(*args)
         return state, hist
 
     # python fallback: identical math, one jitted step per round
@@ -1439,9 +1556,9 @@ def _run_federated(problem, x0, data, schedule, spec, key, n_rounds, *,
         else:
             batch = jax.tree.map(lambda x: x[t], batches)
         state, m = step_j(state, batch, gammas[t], round_keys[t])
-        m, theta_new, diag_new = round_metrics(state, m, gammas[t],
-                                               theta_prev, diag_prev,
-                                               jnp.asarray(t))
+        m, theta_new, diag_new = _round_metrics(cfg, eval_batch, state, m,
+                                                gammas[t], theta_prev,
+                                                diag_prev, jnp.asarray(t))
         if track_mirror:
             theta_prev = theta_new
         if diag_fn is not None:
