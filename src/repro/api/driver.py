@@ -28,7 +28,7 @@ from jax.sharding import PartitionSpec
 
 from ..core.compression import (_tree_bytes, verify_payload, weighted_sum,
                                 zero_invalid_rows)
-from ..core.surrogate import (tree_lerp, tree_scale, tree_sub, tree_sq_norm,
+from ..core.surrogate import (tree_lerp, tree_sub, tree_sq_norm,
                               tree_sq_norm_ew)
 from .problem import MMProblem, as_problem
 from .schedule import resolve_schedule, schedule_length
@@ -196,11 +196,20 @@ def centralized_init(problem, s0) -> DriverState:
 # step
 # ---------------------------------------------------------------------------
 
+def _acc(x):
+    """Arithmetic dtype of a leaf: at least float32, so that a scalar
+    coefficient is never rounded to a bf16 leaf's precision first."""
+    return jnp.promote_types(x.dtype, jnp.float32)
+
+
 def _variate_update(v, q, coef):
-    """Lines 8/11/17: V <- V + coef * q, leaf-wise (coef = alpha/p). The
-    ONE definition every client-stage branch shares — scan body, reduce
-    stage and gather tail must apply the identical update rule."""
-    return jax.tree.map(lambda vv, dq: vv + coef * dq, v, q)
+    """Lines 8/11/17: V <- V + coef * q, leaf-wise (coef = alpha/p),
+    computed in ``_acc`` and rounded once to V's dtype. The ONE definition
+    every client-stage branch shares — scan body, reduce stage and gather
+    tail must apply the identical update rule."""
+    return jax.tree.map(
+        lambda vv, dq: (vv.astype(_acc(vv)) + coef * dq.astype(_acc(vv)))
+        .astype(vv.dtype), v, q)
 
 
 def _weighted_reduce(w, q):
@@ -266,6 +275,11 @@ def finalize_partial(spec: FederationSpec, agg, key, x_ref):
     if not topo.is_two_tier:
         return agg, 0.0
     return tier_boundary(spec, agg, _edge_keys(key, topo.n_edges), x_ref)
+
+
+# the per-client flag of a non-finite oracle output, carried with the
+# problem's own per-client metrics and reported by ``step`` as n_nonfinite
+_NONFINITE = "_nonfinite"
 
 
 def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
@@ -352,6 +366,12 @@ def _client_stage(problem: MMProblem, spec: FederationSpec, view, x_ref,
                 s_i, cm = problem.s_bar_metrics(batch, view)   # line 6
             else:
                 s_i, cm = problem.s_bar(batch, view), {}
+            # a non-finite oracle output would reach the wire, whose
+            # zero-scale guard turns each NaN group into zeros: flag it
+            finite = jnp.stack([jnp.all(jnp.isfinite(x))
+                                for x in jax.tree.leaves(s_i)])
+            cm = dict(cm, **{_NONFINITE: jnp.logical_not(
+                jnp.all(finite)).astype(jnp.float32)})
             out = problem.T(s_i) if param_space else s_i   # eq. 21 local MM
         if spec.delta == "oracle":
             d = out                                        # raw payload
@@ -682,7 +702,9 @@ def _server_apply(problem: MMProblem, spec: FederationSpec,
             scale = n / jnp.maximum(n_active, 1.0)
             h = jax.tree.map(lambda a: (scale * a).astype(a.dtype), agg)
         else:
-            h = tree_scale(agg, 1.0 / p)
+            h = jax.tree.map(
+                lambda a: ((1.0 / p) * a.astype(_acc(a))).astype(a.dtype),
+                agg)
         if use_v:
             h = jax.tree.map(lambda v, hh: v + hh.astype(v.dtype), state.v, h)
 
@@ -719,9 +741,8 @@ def _server_apply(problem: MMProblem, spec: FederationSpec,
             opt_new = state.opt
 
         # server control variate (line 17)
-        v_new = (jax.tree.map(
-            lambda v, a: v + ((alpha / p) * a).astype(v.dtype), state.v, agg)
-            if use_v else ())
+        v_new = (_variate_update(state.v, agg, alpha / p)
+                 if use_v else ())
 
         # problem-owned server state (FedMM-OT line 16: conjugate update)
         if problem.server_step is not None:
@@ -947,6 +968,7 @@ def step(problem: MMProblem, spec: FederationSpec, state: DriverState,
     new_state, h, aux_metrics = _server_apply(
         problem, spec, state, agg, v_i_new, n_survive, gamma)
     x_new = new_state.x
+    nonfinite = cmetrics.pop(_NONFINITE)
 
     comm = comp.round_metrics(state.x, p=p)
     per_client = (wire_bytes_client if use_wire
@@ -976,6 +998,8 @@ def step(problem: MMProblem, spec: FederationSpec, state: DriverState,
         "backbone_bytes": backbone,
         "comm_bytes": uplink_bytes + backbone,
         "omega_eff": jnp.asarray(comm["omega_eff"], jnp.float32),
+        # participating clients whose oracle output held a NaN or an inf
+        "n_nonfinite": jnp.sum(mask * nonfinite),
     }
     if drift_metric:
         # E^s (surrogate) / E^p (parameter) — the Section 6 diagnostics.
@@ -1088,6 +1112,7 @@ def _cohort_partial(problem: MMProblem, spec: FederationSpec,
     comm = comp.round_metrics(state.x, p=spec.participation)
     per_client = (wire_bytes_client if use_wire
                   else comm["payload_bytes_per_client"])
+    nonfinite = cmetrics.pop(_NONFINITE)
     if cohort.valid is None:
         metric_sums = {k: jnp.sum(v, axis=0) for k, v in cmetrics.items()}
     else:
@@ -1098,6 +1123,9 @@ def _cohort_partial(problem: MMProblem, spec: FederationSpec,
             k: jnp.sum(v * valid.reshape((c,) + (1,) * (v.ndim - 1)),
                        axis=0)
             for k, v in cmetrics.items()}
+    # a count, not a mean: the scheduler sums it over the round's cohorts
+    # (padded slots carry a zero mask)
+    metric_sums[_NONFINITE] = jnp.sum(mask * nonfinite)
     return CohortPartial(
         # wire-verification survivors (== sum(mask) without checksums):
         # a corrupt client is excluded from the normalization count...

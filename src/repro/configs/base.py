@@ -25,7 +25,22 @@ class ArchConfig:
     top_k: int = 0
     capacity_factor: float = 1.25
     moe_every: int = 1             # apply MoE every k-th layer (jamba: 2)
-    moe_group: int = 256           # one-hot dispatch group size (perf lever)
+    moe_group: int = 256           # one-hot dispatch group size; rows per
+                                   # expert tile on the held-share path
+    d_expert: int = 0              # routed/shared expert width (0 -> d_ff)
+    n_shared_experts: int = 0      # always-on experts, one SwiGLU of
+                                   # width n_shared_experts * d_expert
+    first_dense: int = 0           # leading dense layers before the MoE stack
+    routed_scale: float = 1.0      # multiplies the normalised top-k gates
+    experts_held: int = 0          # 0: every expert, capacity dispatch; else
+                                   # this chip's share [expert_base,
+                                   # expert_base + experts_held), dropless
+    expert_base: int = 0
+    # multi-head latent attention (DeepSeek-V2); on when kv_lora_rank > 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # attention pattern
     window: int = 0                # sliding-window size (0 = full attention)
     global_every: int = 0          # gemma3: 1 global layer every k (k=6 -> 5:1)

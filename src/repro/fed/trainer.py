@@ -129,9 +129,13 @@ def choose_client_layout(n_params: int, multi_pod: bool):
 
 
 def T_map(s_hat, cfg: FedLMConfig):
-    """MM-2 minimizer: prox of the l2 penalty — exact and elementwise."""
+    """MM-2 minimizer: prox of the l2 penalty — exact and elementwise, in
+    at least float32 and rounded once to each leaf's dtype (a bf16 c would
+    shrink by 1 - 0.99609 where 1/(1 + 0.005) asks for 0.995)."""
     c = 1.0 / (1.0 + cfg.rho * cfg.weight_decay)
-    return jax.tree.map(lambda x: (c * x).astype(x.dtype), s_hat)
+    return jax.tree.map(
+        lambda x: (c * x.astype(jnp.promote_types(x.dtype, jnp.float32)))
+        .astype(x.dtype), s_hat)
 
 
 def init_state(model: Model, key, cfg: FedLMConfig) -> FedLMState:
@@ -150,20 +154,62 @@ def init_state(model: Model, key, cfg: FedLMConfig) -> FedLMState:
                       opt=opt)
 
 
+PROBE_SIZE = 4096
+
+
+def grad_probe(g, size: int = PROBE_SIZE):
+    """The gradient at a fixed grid of each leaf's coordinates, in float32,
+    as one flat vector: along every axis of length d, every
+    ``max(1, d // m)``-th index, with ``m = round(size ** (1 / ndim))``,
+    so about ``size`` coordinates a leaf. A bf16 oracle output keeps
+    nothing of ``rho * g`` where it is under half a step of theta; this
+    view of g keeps the gradient itself, element by element, for a check
+    against a reference."""
+    out = []
+    for x in jax.tree.leaves(g):
+        m = max(1, round(size ** (1.0 / x.ndim))) if x.ndim else 1
+        x = x[tuple(slice(None, None, max(1, d // m)) for d in x.shape)]
+        out.append(x.astype(jnp.float32).reshape(-1))
+    return jnp.concatenate(out)
+
+
+def _rounded(x, dtype):
+    """``x`` rounded to ``dtype`` by an explicit op: XLA, allowed excess
+    precision, drops a convert whose result is converted back up, so on
+    the TPU the oracle output reached the drift unrounded (carrying the
+    ``rho * g`` that its bf16 value loses) wherever the two fused."""
+    dtype = jnp.dtype(dtype)
+    if dtype == x.dtype:
+        return x
+    fi = jnp.finfo(dtype)
+    return jax.lax.reduce_precision(
+        x, exponent_bits=fi.nexp, mantissa_bits=fi.nmant).astype(dtype)
+
+
 def make_problem(model: Model, cfg: FedLMConfig) -> "api.MMProblem":
     """This trainer's workload as the ONE ``api.MMProblem``: the quadratic
     surrogate (Example 1) on ``model.loss_fn`` — per-client oracle
-    S_i = theta - rho * grad_i(theta) (dtype-preserving: the f32 grads cast
-    back into the parameter dtype), T = the l2 prox, projection = identity
-    (S = R^q). ``s_bar_metrics`` surfaces the per-client loss from the same
-    ``value_and_grad`` call, so the driver's metrics carry the trainer's
-    ``loss`` without a second forward pass."""
+    S_i = theta - rho * grad_i(theta) (dtype-preserving: computed in at
+    least float32 and rounded once to the parameter dtype), T = the l2
+    prox, projection = identity (S = R^q). ``s_bar_metrics`` surfaces the
+    per-client loss from the same ``value_and_grad`` call, so the driver's
+    metrics carry the trainer's ``loss`` without a second forward pass,
+    the client's ``grad_probe``, and, for a model that holds a share of
+    its experts (``model.loss_stats``), each client's ``expert_load``."""
 
     def s_bar_metrics(cb, theta):
-        loss, g = jax.value_and_grad(model.loss_fn)(theta, cb)
-        s_i = jax.tree.map(
-            lambda th, gg: th - cfg.rho * gg.astype(th.dtype), theta, g)
-        return s_i, {"loss": loss}
+        if model.loss_stats is None:
+            loss, g = jax.value_and_grad(model.loss_fn)(theta, cb)
+            stats = {}
+        else:
+            (loss, stats), g = jax.value_and_grad(
+                model.loss_stats, has_aux=True)(theta, cb)
+        def oracle(th, gg):
+            acc = jnp.promote_types(th.dtype, jnp.float32)
+            return _rounded(th.astype(acc) - cfg.rho * gg.astype(acc),
+                            th.dtype)
+        s_i = jax.tree.map(oracle, theta, g)
+        return s_i, {"loss": loss, "grad_probe": grad_probe(g), **stats}
 
     return api.MMProblem(
         s_bar=lambda cb, theta: s_bar_metrics(cb, theta)[0],
@@ -218,7 +264,16 @@ def make_train_step(model: Model, cfg: FedLMConfig, mesh=None,
         # driver's h_norm_sq), loss the all-client mean off s_bar_metrics
         metrics = {"loss": m["loss"], "e_s": m["h_norm_sq"],
                    "n_active": m["n_active"], "comm_bytes": m["comm_bytes"],
-                   "omega_eff": m["omega_eff"]}
+                   "omega_eff": m["omega_eff"],
+                   "n_nonfinite": m["n_nonfinite"],
+                   # the all-client mean of the oracles' gradient probes
+                   "grad_probe": m["grad_probe"]}
+        if "expert_load" in m:
+            # the driver means per-client metrics over all n clients, each
+            # of which ran its oracle: the round's assignments per held
+            # expert per MoE layer are n times that mean
+            metrics["expert_load"] = jnp.round(
+                m["expert_load"] * spec.n_clients).astype(jnp.int32)
         if "collective_payload_bytes" in m:
             metrics["collective_payload_bytes"] = \
                 m["collective_payload_bytes"]

@@ -3,9 +3,11 @@ cross), SwiGLU MLP, embeddings. Pure functions over param dicts; bf16-friendly
 (norm + softmax statistics in f32)."""
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .sharding import shard
 
@@ -188,62 +190,165 @@ def blocked_attention(q, k, v, *, causal=True, window=0,
     """Memory-bounded GQA attention with online softmax (flash-style, pure
     jnp — this is also the oracle mirrored by kernels/flash_attention.py).
 
-    q: (B, Sq, H, hd); k, v: (B, Sk, KV, hd). Never materializes (Sq, Sk).
-    lax.map over query blocks (sequential), lax.scan over KV blocks with the
-    (m, l, acc) running-softmax carry.
+    q: (B, Sq, H, dk); k: (B, Sk, KV, dk); v: (B, Sk, KV, dv) — the value
+    width may differ from the query/key width (latent attention). Never
+    materializes (Sq, Sk). Blocks that the mask empties entirely (above
+    the causal diagonal, outside the window) are skipped. The backward pass
+    is its own (``_flash_bwd``): it keeps O(S) residuals — the output and
+    each row's f32 log-sum-exp — and recomputes each block's probabilities,
+    with softmax statistics and the score gradient in f32.
     """
-    B, Sq, H, hd = q.shape
+    return _flash(q, k, v, causal, window, min(q_block, q.shape[1]),
+                  min(kv_block, k.shape[1]))
+
+
+def _blocks(q, k, v, QB, KB):
+    """Pad q to whole query blocks and k/v to whole key blocks; q grouped
+    as (B, nq, QB, KV, G, dk)."""
+    B, Sq, H, dk = q.shape
     Sk, KV = k.shape[1], k.shape[2]
+    nq, nk = -(-Sq // QB), -(-Sk // KB)
+    q = jnp.pad(q, ((0, 0), (0, nq * QB - Sq), (0, 0), (0, 0)))
+    k = jnp.pad(k, ((0, 0), (0, nk * KB - Sk), (0, 0), (0, 0)))
+    v = jnp.pad(v, ((0, 0), (0, nk * KB - Sk), (0, 0), (0, 0)))
+    return q.reshape(B, nq, QB, KV, H // KV, dk), k, v, nq, nk
+
+
+def _block_mask(qi, ki, QB, KB, Sk, causal, window):
+    """(QB, KB) mask of query block ``qi`` against key block ``ki``, and
+    whether any entry of it is set."""
+    q_pos = qi * QB + jnp.arange(QB)
+    k_pos = ki * KB + jnp.arange(KB)
+    mask = jnp.broadcast_to(k_pos[None, :] < Sk, (QB, KB))   # key padding
+    live = ki * KB < Sk
+    if causal:
+        mask &= k_pos[None, :] <= q_pos[:, None]
+        live &= ki * KB <= qi * QB + QB - 1
+    if window:
+        mask &= k_pos[None, :] > q_pos[:, None] - window
+        live &= ki * KB + KB - 1 > qi * QB - window
+    return mask, live
+
+
+def _scores(qblk, kblk, mask, scale):
+    """f32 logits (B, KV, G, QB, KB), masked entries at NEG_INF."""
+    s = jnp.einsum("bqkgh,bskh->bkgqs", qblk, kblk,
+                   preferred_element_type=jnp.float32) * scale
+    return jnp.where(mask[None, None, None], s, NEG_INF)
+
+
+def _flash_fwd(q, k, v, causal, window, QB, KB):
+    B, Sq, H, dk = q.shape
+    Sk, KV, dv = k.shape[1], k.shape[2], v.shape[-1]
     G = H // KV
-    QB = min(q_block, Sq)
-    KB = min(kv_block, Sk)
-    # pad to multiples
-    nq = -(-Sq // QB)
-    nk = -(-Sk // KB)
-    q_pad, k_pad = nq * QB - Sq, nk * KB - Sk
-    if q_pad:
-        q = jnp.pad(q, ((0, 0), (0, q_pad), (0, 0), (0, 0)))
-    if k_pad:
-        k = jnp.pad(k, ((0, 0), (0, k_pad), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, k_pad), (0, 0), (0, 0)))
-    qr = q.reshape(B, nq, QB, KV, G, hd)
-    scale = 1.0 / jnp.sqrt(hd)
+    qr, kp, vp, nq, nk = _blocks(q, k, v, QB, KB)
+    scale = 1.0 / float(np.sqrt(dk))
+    # block indices that depend on an input: under jax.checkpoint, values
+    # computed from constants alone (the masks) are saved as residuals
+    # rather than recomputed, which would stack every block's mask
+    zero = jax.lax.stop_gradient(q.reshape(-1)[0]).astype(jnp.int32) * 0
 
     def one_q_block(qi):
-        qblk = qr[:, qi]                                     # (B, QB, KV, G, hd)
-        q_pos = qi * QB + jnp.arange(QB)
+        qblk = qr[:, qi]                                  # (B, QB, KV, G, dk)
 
         def kv_step(carry, ki):
-            m, l, acc = carry
-            kblk = jax.lax.dynamic_slice(k, (0, ki * KB, 0, 0), (B, KB, KV, hd))
-            vblk = jax.lax.dynamic_slice(v, (0, ki * KB, 0, 0), (B, KB, KV, hd))
-            k_pos = ki * KB + jnp.arange(KB)
-            logits = jnp.einsum("bqkgh,bskh->bkgqs", qblk, kblk)
-            logits = logits.astype(jnp.float32) * scale      # (B, KV, G, QB, KB)
-            mask = k_pos[None, :] < Sk                       # padding
-            if causal:
-                mask &= k_pos[None, :] <= q_pos[:, None]
-            if window:
-                mask &= k_pos[None, :] > q_pos[:, None] - window
-            logits = jnp.where(mask[None, None, None], logits, NEG_INF)
-            m_new = jnp.maximum(m, logits.max(axis=-1))
-            corr = jnp.exp(m - m_new)
-            p = jnp.exp(logits - m_new[..., None])
-            l_new = l * corr + p.sum(axis=-1)
-            pv = jnp.einsum("bkgqs,bskh->bkgqh", p.astype(vblk.dtype), vblk)
-            acc_new = acc * corr[..., None].astype(acc.dtype) + pv
-            return (m_new, l_new, acc_new), None
+            mask, live = _block_mask(qi, ki, QB, KB, Sk, causal, window)
+
+            def update(carry):
+                m, l, acc = carry
+                kblk = jax.lax.dynamic_slice_in_dim(kp, ki * KB, KB, 1)
+                vblk = jax.lax.dynamic_slice_in_dim(vp, ki * KB, KB, 1)
+                s = _scores(qblk, kblk, mask, scale)
+                m_new = jnp.maximum(m, s.max(axis=-1))
+                corr = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new[..., None])
+                l_new = l * corr + p.sum(axis=-1)
+                pv = jnp.einsum("bkgqs,bskh->bkgqh", p.astype(vblk.dtype),
+                                vblk, preferred_element_type=jnp.float32)
+                return m_new, l_new, acc * corr[..., None] + pv
+
+            return jax.lax.cond(live, update, lambda c: c, carry), None
 
         m0 = jnp.full((B, KV, G, QB), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, QB), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, QB, hd), v.dtype)
-        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0), jnp.arange(nk))
-        out = acc / jnp.maximum(l, 1e-30)[..., None].astype(acc.dtype)
-        return jnp.moveaxis(out, 3, 1)                       # (B, QB, KV, G, hd)
+        a0 = jnp.zeros((B, KV, G, QB, dv), jnp.float32)
+        (m, l, acc), _ = jax.lax.scan(kv_step, (m0, l0, a0),
+                                      jnp.arange(nk) + zero)
+        l = jnp.maximum(l, 1e-30)
+        out = (acc / l[..., None]).astype(v.dtype)
+        return jnp.moveaxis(out, 3, 1), m + jnp.log(l)   # (B, QB, KV, G, dv)
 
-    outs = jax.lax.map(one_q_block, jnp.arange(nq))          # (nq, B, QB, KV, G, hd)
-    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * QB, H, hd)
-    return out[:, :Sq]
+    outs, lse = jax.lax.map(one_q_block, jnp.arange(nq) + zero)
+    out = jnp.moveaxis(outs, 0, 1).reshape(B, nq * QB, H, dv)[:, :Sq]
+    return out, (q, k, v, out, lse)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal, window, QB, KB):
+    return _flash_fwd(q, k, v, causal, window, QB, KB)[0]
+
+
+def _flash_bwd(causal, window, QB, KB, res, dout):
+    q, k, v, out, lse = res                  # lse: (nq, B, KV, G, QB)
+    with jax.named_scope("model.attention.bwd"):
+        B, Sq, H, dk = q.shape
+        Sk, KV, dv = k.shape[1], k.shape[2], v.shape[-1]
+        G = H // KV
+        qr, kp, vp, nq, nk = _blocks(q, k, v, QB, KB)
+        scale = 1.0 / float(np.sqrt(dk))
+        pad = ((0, 0), (0, nq * QB - Sq), (0, 0), (0, 0))
+        dor = jnp.pad(dout, pad).reshape(B, nq, QB, KV, G, dv)
+        # D_i = sum_j P_ij dP_ij = rowsum(dO * O), in f32
+        delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)
+        delta = jnp.pad(delta, pad[:3]).reshape(B, nq, QB, KV, G)
+        delta = jnp.transpose(delta, (1, 0, 3, 4, 2))    # like lse
+
+        def kv_block(dq, ki):
+            kblk = jax.lax.dynamic_slice_in_dim(kp, ki * KB, KB, 1)
+            vblk = jax.lax.dynamic_slice_in_dim(vp, ki * KB, KB, 1)
+
+            def q_step(carry, qi):
+                mask, live = _block_mask(qi, ki, QB, KB, Sk, causal, window)
+
+                def update(carry):
+                    dq, dk_b, dv_b = carry
+                    qblk, doblk = qr[:, qi], dor[:, qi]
+                    s = _scores(qblk, kblk, mask, scale)
+                    p = jnp.where(mask[None, None, None],
+                                  jnp.exp(s - lse[qi][..., None]), 0.0)
+                    dv_b = dv_b + jnp.einsum(
+                        "bkgqs,bqkgh->bskh", p.astype(v.dtype), doblk,
+                        preferred_element_type=jnp.float32)
+                    dp = jnp.einsum("bqkgh,bskh->bkgqs", doblk, vblk,
+                                    preferred_element_type=jnp.float32)
+                    ds = (p * (dp - delta[qi][..., None])).astype(q.dtype)
+                    dq_i = jnp.einsum("bkgqs,bskh->bqkgh", ds, kblk,
+                                      preferred_element_type=jnp.float32)
+                    dq = dq.at[:, qi].add(dq_i * scale)
+                    dk_b = dk_b + scale * jnp.einsum(
+                        "bkgqs,bqkgh->bskh", ds, qblk,
+                        preferred_element_type=jnp.float32)
+                    return dq, dk_b, dv_b
+
+                return jax.lax.cond(live, update, lambda c: c, carry), None
+
+            zk = jnp.zeros((B, KB, KV, dk), jnp.float32)
+            zv = jnp.zeros((B, KB, KV, dv), jnp.float32)
+            (dq, dk_b, dv_b), _ = jax.lax.scan(q_step, (dq, zk, zv),
+                                               jnp.arange(nq))
+            return dq, (dk_b, dv_b)
+
+        dq0 = jnp.zeros(qr.shape, jnp.float32)
+        dq, (dkb, dvb) = jax.lax.scan(kv_block, dq0, jnp.arange(nk))
+        dq = dq.reshape(B, nq * QB, H, dk)[:, :Sq]
+        dk_ = jnp.moveaxis(dkb, 0, 1).reshape(B, nk * KB, KV, dk)[:, :Sk]
+        dv_ = jnp.moveaxis(dvb, 0, 1).reshape(B, nk * KB, KV, dv)[:, :Sk]
+    return dq.astype(q.dtype), dk_.astype(k.dtype), dv_.astype(v.dtype)
+
+
+_flash.defvjp(lambda q, k, v, causal, window, QB, KB:
+              _flash_fwd(q, k, v, causal, window, QB, KB), _flash_bwd)
 
 
 def full_seq_attention(params, cfg, x, *, causal=True, window=0, kv_x=None,
@@ -266,6 +371,48 @@ def full_seq_attention(params, cfg, x, *, causal=True, window=0, kv_x=None,
     out = blocked_attention(q, k, v, causal=causal, window=window)
     out = out.reshape(out.shape[:2] + (H * hd,))
     return out @ params["wo"], k, v
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1)
+# ---------------------------------------------------------------------------
+
+def mla_init(key, cfg, dtype):
+    """Queries projected directly (no q low rank); keys and values through
+    a ``kv_lora_rank`` latent with one shared rotary key of
+    ``qk_rope_head_dim``."""
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    return {"wq": dense_init(k1, cfg.d_model, H * (dn + dr), dtype),
+            "wkv_a": dense_init(k2, cfg.d_model, r + dr, dtype),
+            "kv_norm": rmsnorm_init(r, dtype),
+            "wkv_b": dense_init(k3, r, H * (dn + dv), dtype),
+            "wo": dense_init(k4, H * dv, cfg.d_model, dtype)}
+
+
+def mla_attention(params, cfg, x):
+    """Causal latent attention over the whole sequence. x: (B, S, d).
+    Each head's key is [k_nope_h, k_rope] (width dn + dr), its query
+    [q_nope_h, q_rope_h]; the softmax scale is 1/sqrt(dn + dr) and the
+    value width dv."""
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("model.mla"):
+        pos = jnp.arange(S)[None]
+        q = _split_heads(x @ params["wq"], H, dn + dr)
+        q = jnp.concatenate(
+            [q[..., :dn], rope(q[..., dn:], pos, cfg.rope_theta)], axis=-1)
+        ckv = x @ params["wkv_a"]
+        c = rmsnorm(params["kv_norm"], ckv[..., :r], cfg.norm_eps)
+        k_rope = rope(ckv[..., None, r:], pos, cfg.rope_theta)  # (B,S,1,dr)
+        kv = _split_heads(c @ params["wkv_b"], H, dn + dv)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))], axis=-1)
+        v = kv[..., dn:]
+    out = blocked_attention(q, k, v, causal=True)
+    return out.reshape(B, S, H * dv) @ params["wo"]
 
 
 # ---------------------------------------------------------------------------
@@ -325,6 +472,9 @@ def chunked_softmax_xent(params, x, labels, cfg, chunk: int = 128):
     head = params["lm_head"]
     vmask = (jnp.arange(cfg.padded_vocab) < cfg.vocab)
 
+    # recomputed in the backward pass: otherwise every chunk's f32 logits
+    # stay live as residuals, (S / chunk) x B x chunk x V x 4 bytes
+    @jax.checkpoint
     def chunk_loss(xc, yc):
         lg = (xc @ head.T).astype(jnp.float32)
         lg = jnp.where(vmask, lg, NEG_INF)

@@ -11,7 +11,7 @@ current position; returns next-token logits + updated cache.
 """
 from __future__ import annotations
 
-from typing import Callable, NamedTuple
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +29,9 @@ class Model(NamedTuple):
     prefill: Callable         # (params, batch) -> (last_logits, cache)
     decode: Callable          # (params, cache, token (B,1), pos) -> (logits, cache)
     init_cache: Callable      # (batch_size, seq_len) -> zero cache pytree
+    # (params, batch) -> (loss, {"expert_load": (n_moe_layers, E_held)
+    # int32}) for a model that holds a share of its experts; else None
+    loss_stats: Optional[Callable] = None
 
 
 def _dtype(cfg):
@@ -37,7 +40,7 @@ def _dtype(cfg):
 
 def build_model(cfg: ArchConfig) -> Model:
     dtype = _dtype(cfg)
-    pattern = T.layer_pattern(cfg)
+    lead, pattern = T.split_pattern(cfg)
 
     def init(key):
         k_e, k_s, k_enc, k_n = jax.random.split(key, 4)
@@ -46,24 +49,26 @@ def build_model(cfg: ArchConfig) -> Model:
             "stack": T.stack_init(k_s, cfg, dtype, pattern)["params"],
             "final_norm": L.rmsnorm_init(cfg.d_model, dtype),
         }
+        if lead:
+            params["lead"] = T.stack_init(k_n, cfg, dtype, lead)["params"]
         if cfg.family == "audio":
             enc_pat = ["enc_mlp"] * cfg.n_encoder_layers
             params["encoder"] = T.stack_init(k_enc, cfg, dtype, enc_pat)["params"]
             params["enc_norm"] = L.rmsnorm_init(cfg.d_model, dtype)
         return params
 
-    def _stack(params):
-        c = T._cycle(pattern)
-        return {"kinds": tuple(pattern[:c]), "params": params["stack"],
-                "n_blocks": len(pattern) // c}
+    def _stack(params, name="stack", pat=pattern):
+        c = T._cycle(pat)
+        return {"kinds": tuple(pat[:c]), "params": params[name],
+                "n_blocks": len(pat) // c}
 
     def _enc_stack(params):
         return {"kinds": ("enc_mlp",), "params": params["encoder"],
                 "n_blocks": cfg.n_encoder_layers}
 
     def _encode(params, frames):
-        x, _, _ = T.stack_forward(_enc_stack(params), cfg,
-                                  frames.astype(dtype), want_cache=False)
+        x, _, _, _ = T.stack_forward(_enc_stack(params), cfg,
+                                     frames.astype(dtype), want_cache=False)
         return L.rmsnorm(params["enc_norm"], x)
 
     def _embed_inputs(params, batch):
@@ -79,23 +84,31 @@ def build_model(cfg: ArchConfig) -> Model:
             enc_out = _encode(params, batch["frames"])
         return x, enc_out, n_prefix
 
-    def loss_fn(params, batch):
+    def loss_stats(params, batch):
         x, enc_out, n_prefix = _embed_inputs(params, batch)
-        x, _, aux = T.stack_forward(_stack(params), cfg, x, enc_out,
-                                    want_cache=False, remat=True)
+        if lead:
+            x, _, _, _ = T.stack_forward(_stack(params, "lead", lead), cfg,
+                                         x, want_cache=False, remat=True)
+        x, _, aux, stats = T.stack_forward(_stack(params), cfg, x, enc_out,
+                                           want_cache=False, remat=True)
         x = L.rmsnorm(params["final_norm"], x)
         if n_prefix:
             x = x[:, n_prefix:]
         loss = L.chunked_softmax_xent(params["embedding"], x,
                                       batch["labels"], cfg)
-        return loss + 0.01 * aux
+        counts = [s for s in stats if s is not None]
+        return loss + 0.01 * aux, (
+            {"expert_load": jnp.concatenate(counts)} if counts else {})
+
+    def loss_fn(params, batch):
+        return loss_stats(params, batch)[0]
 
     def prefill(params, batch, cache_len=None):
         """cache_len: optionally allocate full-attention caches longer than
         the prompt (extra slots are masked in decode via the slot<=pos rule)."""
         x, enc_out, n_prefix = _embed_inputs(params, batch)
-        x, caches, _ = T.stack_forward(_stack(params), cfg, x, enc_out,
-                                       want_cache=True, remat=False)
+        x, caches, _, _ = T.stack_forward(_stack(params), cfg, x, enc_out,
+                                          want_cache=True, remat=False)
         if cache_len is not None:
             c = T._cycle(pattern)
             kinds = pattern[:c]
@@ -172,7 +185,8 @@ def build_model(cfg: ArchConfig) -> Model:
         return tuple(one(k) for k in kinds)
 
     return Model(cfg=cfg, init=init, loss_fn=loss_fn, prefill=prefill,
-                 decode=decode, init_cache=init_cache)
+                 decode=decode, init_cache=init_cache,
+                 loss_stats=loss_stats if cfg.experts_held else None)
 
 
 def make_batch(key, cfg: ArchConfig, batch_size: int, seq_len: int):

@@ -9,6 +9,13 @@ Layer "kinds" (composable sublayer patterns):
   mamba_moe  Mamba SSM + MoE                            (jamba)
   cross_mlp  self-attn + cross-attn(enc) + MLP          (whisper decoder)
   enc_mlp    bidirectional attention + MLP              (whisper encoder)
+  mla_mlp    latent attention + MLP                     (moonlight, leading)
+  mla_moe    latent attention + held expert share       (moonlight)
+             + shared experts
+
+Leading dense layers (``cfg.first_dense``) form a stack of their own ahead
+of the scanned cycle (``split_pattern``), so that they never join a
+remat block of the repeating layers.
 
 A model is a repeating *cycle* of kinds (dense: cycle 1; gemma3: cycle 6 =
 5 local + 1 global; jamba: cycle 8 = 7 mamba + 1 attn with MoE every other
@@ -45,6 +52,15 @@ def layer_init(kind, key, cfg, dtype):
                 "attn": L.attention_init(k1, cfg, dtype),
                 "norm2": L.rmsnorm_init(cfg.d_model, dtype),
                 "moe": MOE.moe_init(k2, cfg, dtype)}
+    if kind in ("mla_mlp", "mla_moe"):
+        p = {"norm1": L.rmsnorm_init(cfg.d_model, dtype),
+             "attn": L.mla_init(k1, cfg, dtype),
+             "norm2": L.rmsnorm_init(cfg.d_model, dtype)}
+        if kind == "mla_moe":
+            p["moe"] = MOE.moe_share_init(k2, cfg, dtype)
+        else:
+            p["mlp"] = L.mlp_init(k2, cfg.d_model, cfg.d_ff, dtype)
+        return p
     if kind == "rwkv":
         return R.rwkv_block_init(k1, cfg, dtype)
     if kind == "mamba_mlp":
@@ -68,10 +84,23 @@ def layer_init(kind, key, cfg, dtype):
 
 
 def layer_forward(kind, params, cfg, x, enc_out=None, want_cache=False):
-    """Full-sequence forward. Returns (x, cache_or_None, aux_loss)."""
+    """Full-sequence forward. Returns (x, cache_or_None, aux_loss, stats):
+    ``stats`` is the held experts' assignment counts of an ``mla_moe``
+    layer, else None."""
     aux = jnp.zeros((), jnp.float32)
     use_rope = cfg.family != "hybrid"
     cache = None
+    if kind in ("mla_mlp", "mla_moe"):
+        if want_cache:
+            raise NotImplementedError(
+                "latent attention has no cache here: training only")
+        x = x + L.mla_attention(params["attn"], cfg,
+                                L.rmsnorm(params["norm1"], x))
+        h = L.rmsnorm(params["norm2"], x)
+        if kind == "mla_mlp":
+            return x + L.mlp(params["mlp"], h), None, aux, None
+        h, counts = MOE.moe_share_block(params["moe"], cfg, h)
+        return x + h, None, aux, counts
     if kind in ("attn_mlp", "swa_mlp", "enc_mlp", "attn_moe"):
         window = cfg.window if kind == "swa_mlp" else 0
         causal = kind != "enc_mlp"
@@ -95,10 +124,10 @@ def layer_forward(kind, params, cfg, x, enc_out=None, want_cache=False):
             h, aux = MOE.moe_block(params["moe"], cfg, L.rmsnorm(params["norm2"], x))
         else:
             h = L.mlp(params["mlp"], L.rmsnorm(params["norm2"], x))
-        return x + h, cache, aux
+        return x + h, cache, aux, None
     if kind == "rwkv":
         x, state = R.rwkv_block(params, cfg, x)
-        return x, (state if want_cache else None), aux
+        return x, (state if want_cache else None), aux, None
     if kind in ("mamba_mlp", "mamba_moe"):
         h, state = M.mamba_block(params["mamba"], cfg,
                                  L.rmsnorm(params["norm1"], x))
@@ -107,7 +136,7 @@ def layer_forward(kind, params, cfg, x, enc_out=None, want_cache=False):
             h, aux = MOE.moe_block(params["moe"], cfg, L.rmsnorm(params["norm2"], x))
         else:
             h = L.mlp(params["mlp"], L.rmsnorm(params["norm2"], x))
-        return x + h, (state if want_cache else None), aux
+        return x + h, (state if want_cache else None), aux, None
     if kind == "cross_mlp":
         h, k, v = L.full_seq_attention(
             params["attn"], cfg, L.rmsnorm(params["norm1"], x), causal=True)
@@ -121,7 +150,7 @@ def layer_forward(kind, params, cfg, x, enc_out=None, want_cache=False):
                      "v": shard(v, "batch", "cache_seq", None, None),
                      "ek": ek, "ev": ev}
         h = L.mlp(params["mlp"], L.rmsnorm(params["norm2"], x))
-        return x + h, cache, aux
+        return x + h, cache, aux, None
     raise ValueError(kind)
 
 
@@ -172,6 +201,10 @@ def layer_decode(kind, params, cfg, x, cache, pos):
 
 def layer_pattern(cfg):
     Lh = cfg.n_layers
+    if cfg.kv_lora_rank:
+        return (["mla_mlp"] * cfg.first_dense
+                + ["mla_moe" if cfg.n_experts else "mla_mlp"]
+                * (Lh - cfg.first_dense))
     if cfg.family in ("dense", "vlm") and not cfg.global_every:
         return ["attn_mlp"] * Lh
     if cfg.global_every:  # gemma3: (k-1) local : 1 global
@@ -196,6 +229,12 @@ def layer_pattern(cfg):
     raise ValueError(cfg.family)
 
 
+def split_pattern(cfg):
+    """(leading dense layers, the repeating rest) of the layer pattern."""
+    pattern = layer_pattern(cfg)
+    return pattern[:cfg.first_dense], pattern[cfg.first_dense:]
+
+
 def _cycle(pattern):
     for c in range(1, len(pattern) + 1):
         if len(pattern) % c == 0 and pattern == pattern[:c] * (len(pattern) // c):
@@ -217,20 +256,24 @@ def stack_init(key, cfg, dtype, pattern=None):
 
 
 def stack_forward(stack, cfg, x, enc_out=None, want_cache=False, remat=True):
-    """Scan over cycle blocks. Returns (x, caches (stacked per pos), aux)."""
+    """Scan over cycle blocks. Returns (x, caches (stacked per pos), aux,
+    stats (per cycle position, stacked over blocks; None where a layer
+    keeps none))."""
     kinds = stack["kinds"]
 
     def block(x, block_params):
-        caches, aux = [], jnp.zeros((), jnp.float32)
+        caches, stats, aux = [], [], jnp.zeros((), jnp.float32)
         for kind, p in zip(kinds, block_params):
-            x, cache, a = layer_forward(kind, p, cfg, x, enc_out, want_cache)
+            x, cache, a, st = layer_forward(kind, p, cfg, x, enc_out,
+                                            want_cache)
             caches.append(cache)
+            stats.append(st)
             aux = aux + a
-        return x, (tuple(caches), aux)
+        return x, (tuple(caches), aux, tuple(stats))
 
     body = jax.checkpoint(block) if remat else block
-    x, (caches, aux) = jax.lax.scan(body, x, stack["params"])
-    return x, caches, jnp.sum(aux)
+    x, (caches, aux, stats) = jax.lax.scan(body, x, stack["params"])
+    return x, caches, jnp.sum(aux), stats
 
 
 def stack_decode(stack, cfg, x, caches, pos):

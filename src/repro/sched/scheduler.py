@@ -87,9 +87,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..api.driver import (CohortPartial, CohortSlice, DriverState,
-                          _stack_metrics, apply_partial, finalize_partial,
-                          step)
+from ..api.driver import (_NONFINITE, CohortPartial, CohortSlice,
+                          DriverState, _stack_metrics, apply_partial,
+                          finalize_partial, step)
 from ..analysis import hb
 from ..api.problem import as_problem
 from ..api.schedule import resolve_schedule, schedule_length
@@ -529,7 +529,8 @@ class CohortScheduler:
         if buffer.collective_payload_bytes is not None:
             m["collective_payload_bytes"] = jnp.asarray(
                 buffer.collective_payload_bytes, jnp.float32)
-        sums = buffer.metric_sums or {}
+        sums = dict(buffer.metric_sums or {})
+        m["n_nonfinite"] = sums.pop(_NONFINITE, jnp.float32(0.0))
         dup = set(sums) & set(m)
         if dup:
             raise ValueError(f"s_bar_metrics keys {sorted(dup)} collide "
