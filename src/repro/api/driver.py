@@ -1194,6 +1194,42 @@ def _stack_batches(batch_list):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *batch_list)
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _key_chain(key, n_rounds):
+    """The host key chain as one program: per round
+    ``key, k_round, k_batch = split(key, 3)``, scanned, returning the
+    stacked ``(round_keys, batch_keys)``. Threefry is integer arithmetic,
+    so the bits equal the eager chain's; jit keeps one program per
+    ``n_rounds`` and key aval."""
+    def body(k, _):
+        k, k_round, k_batch = jax.random.split(k, 3)
+        return k, (k_round, k_batch)
+    _, keys = jax.lax.scan(body, key, length=n_rounds)
+    return keys
+
+
+def _round_keys(key, n_rounds):
+    """``run``'s per-round ``(round_keys, batch_keys)``: the round keys
+    stacked, the batch keys a per-round list. One compiled ``_key_chain``
+    call, unstacked in a few chunked dispatches; eager, one split a
+    round, under an active ``KeyAudit``, whose patched ``split`` must see
+    concrete keys. Records ``/fedmm/run/keys/compiled`` or
+    ``/fedmm/run/keys/eager``."""
+    if getattr(jax.random.split, "_repro_key_audit", False):
+        jax.monitoring.record_event("/fedmm/run/keys/eager")
+        round_keys, batch_keys = [], []
+        for _ in range(n_rounds):
+            key, k_round, k_batch = jax.random.split(key, 3)
+            round_keys.append(k_round)
+            batch_keys.append(k_batch)
+        return jnp.stack(round_keys), batch_keys
+    jax.monitoring.record_event("/fedmm/run/keys/compiled")
+    round_keys, batch_keys = _key_chain(key, n_rounds)
+    # iterating the array unstacks it in chunks of 100 rounds; indexing
+    # it once per round would dispatch n_rounds times
+    return round_keys, list(batch_keys)
+
+
 class _Trajectory(NamedTuple):
     """Everything that decides the traced program of a federated
     ``run(scan=True)``: the static half of its cache key. The arrays
@@ -1372,6 +1408,12 @@ def run(problem, x0, data, schedule, *, spec: Optional[FederationSpec] = None,
         a static ``(n, ...)`` pytree reused every round (exact local
         expectations, e.g. Figure 2).
 
+    key: the federated key chain's root. Each round takes
+    ``key, k_round, k_batch = split(key, 3)``, the legacy loops'
+    derivation; the chain is built in one compiled ``lax.scan`` per
+    ``n_rounds`` (the same bits as the eager loop), and with one eager
+    split a round while a ``KeyAudit`` is active, so the audit sees each.
+
     track_mirror: record ``e_p_s`` — mirror-sequence movement
     ||T(x_{t+1}) - T(x_t)||^2 / gamma^2 (surrogate aggregation only).
     diag: optional ``(name, fn)``; records ||fn(x_{t+1}) - fn(x_t)||^2 /
@@ -1483,12 +1525,7 @@ def _run_federated(problem, x0, data, schedule, spec, key, n_rounds, *,
     # host-side key chain — replicates the legacy run loops exactly:
     # each round consumes (k_round, k_batch) off the same chain
     with span("run.keys"):
-        round_keys, batch_keys = [], []
-        for t in range(n_rounds):
-            key, k_round, k_batch = jax.random.split(key, 3)
-            round_keys.append(k_round)
-            batch_keys.append(k_batch)
-        round_keys = jnp.stack(round_keys)
+        round_keys, batch_keys = _round_keys(key, n_rounds)
     static = not callable(data)
     lazy = False
     budget = (SCAN_BATCH_BYTES_MAX if scan_batch_bytes_max is None
