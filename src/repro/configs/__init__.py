@@ -5,7 +5,7 @@ from __future__ import annotations
 
 import importlib
 
-from .base import ArchConfig, InputShape, INPUT_SHAPES  # noqa: F401
+from .base import ArchConfig  # noqa: F401
 
 ARCH_IDS = [
     "phi3-medium-14b",

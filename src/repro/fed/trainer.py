@@ -22,17 +22,16 @@ It owns no client loop either: ``make_train_step`` adapts the model into an
 ``api.step`` call — physical silos on the driver's batched/shard_mapped
 path, logical clients on its sequential-scan mode (see below).
 
-Client topology (DESIGN.md §3):
+Client topology (the layouts ``state_specs`` gives):
   physical  n = |pod| x |data| silos; V_i / grads carry a leading client dim
             sharded over ('pod','data'); inner dims sharded over 'model'.
             The uplink aggregation IS the cross-silo all-reduce.
             Memory: ~6 param-sized buffers / 16 devices -> P <~ 20B.
   logical   n in {2, 4} simulated clients; the client dim is local and inner
-            dims are sharded over the whole mesh (ZeRO-style). Used for the
-            >=26B configs, where per-client control variates at parameter
-            granularity exceed a silo's HBM (this memory equation is a real
-            deployment constraint of FedMM-with-quadratic-surrogates — see
-            EXPERIMENTS.md notes).
+            dims are sharded over the whole mesh (ZeRO-style). For models
+            past that size, where per-client control variates at parameter
+            granularity exceed a silo's HBM (a real deployment constraint
+            of FedMM with quadratic surrogates).
 """
 from __future__ import annotations
 
@@ -57,8 +56,6 @@ class FedLMConfig:
     weight_decay: float = 0.1      # g(theta) = wd/2 ||theta||^2
     p: float = 1.0                 # participation probability (A5)
     alpha: float = 0.1             # control-variate step
-    attn_mode: str = "sharded"     # "replicated" = §Perf attention variant
-    mlp_mode: str = "generic"      # "megatron" = §Perf paired row-parallel
     quant_bits: int = 8            # 0 -> no compression
     quant_block: int = 256
     quant_dither: str = "hash"     # fused-hash dither (zero-memory at scale)
@@ -116,16 +113,6 @@ def param_count(model: Model) -> int:
     shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     return sum(int(jnp.prod(jnp.asarray(l.shape))) if l.shape else 1
                for l in jax.tree.leaves(shapes))
-
-
-def choose_client_layout(n_params: int, multi_pod: bool):
-    """(n_clients, mode) under the per-client control-variate memory budget."""
-    silos = 32 if multi_pod else 16
-    if n_params <= 2.0e10:
-        return silos, "physical"
-    if n_params <= 1.5e11:
-        return 4, "logical"
-    return 2, "logical"
 
 
 def T_map(s_hat, cfg: FedLMConfig):
@@ -287,7 +274,7 @@ def make_train_step(model: Model, cfg: FedLMConfig, mesh=None,
 
 
 # ---------------------------------------------------------------------------
-# sharding specs for the FedMM state + batches (consumed by launch/dryrun.py)
+# sharding specs for the FedMM state + batches
 # ---------------------------------------------------------------------------
 
 def state_specs(params_shapes, cfg: FedLMConfig, fsdp, tp="model",
@@ -295,19 +282,15 @@ def state_specs(params_shapes, cfg: FedLMConfig, fsdp, tp="model",
     """PartitionSpec pytrees for (s_hat, v, v_i) given the eval_shape of the
     params. physical: client dim over the fsdp axes, inner dims over tp only.
     logical: client dim unsharded, inner dims over (fsdp, tp)."""
-    attn_mode = getattr(cfg, "attn_mode", "sharded")
-    mlp_mode = getattr(cfg, "mlp_mode", "generic")
     use_cv = cfg.federation_spec().use_variates
     if cfg.client_mode == "physical":
         pspec = shd.param_specs(params_shapes, fsdp=(), fsdp_size=10**9,
-                                tp=tp, tp_size=tp_size, attn_mode=attn_mode,
-                                mlp_mode=mlp_mode)
+                                tp=tp, tp_size=tp_size)
         vi_spec = jax.tree.map(lambda s: P(fsdp, *s), pspec,
                                is_leaf=lambda x: isinstance(x, P))
     else:
         pspec = shd.param_specs(params_shapes, fsdp=fsdp, fsdp_size=fsdp_size,
-                                tp=tp, tp_size=tp_size, attn_mode=attn_mode,
-                                mlp_mode=mlp_mode)
+                                tp=tp, tp_size=tp_size)
         vi_spec = jax.tree.map(lambda s: P(None, *s), pspec,
                                is_leaf=lambda x: isinstance(x, P))
     if not use_cv:

@@ -1,9 +1,9 @@
 """Pallas TPU kernel: GQA flash attention (causal / sliding window).
 
-TPU-native tiling (DESIGN.md hardware-adaptation notes): the MXU wants
-128-aligned matmul dims, so Q/K tiles are (QB, hd) x (KB, hd) with QB, KB
-multiples of 128 when the sequence allows; the online-softmax running state
-(m, l, acc) lives in VMEM scratch across the KV-block grid dimension.
+TPU-native tiling: the MXU wants 128-aligned matmul dims, so Q/K tiles are
+(QB, hd) x (KB, hd) with QB, KB multiples of 128 when the sequence allows;
+the online-softmax running state (m, l, acc) lives in VMEM scratch across
+the KV-block grid dimension.
 
 Grid: (batch*kv_heads*q_groups, n_q_blocks, n_kv_blocks); the KV dimension is
 the innermost (sequential) axis so the carry is valid. Causal + window

@@ -1,36 +1,15 @@
-"""Production mesh + logical-axis rule installation.
+"""Device meshes for the federated client stage.
 
-The target is a TPU v5e pod-slice: 256 chips per pod arranged (16, 16) as
-('data', 'model'); the 2-pod production job is (2, 16, 16) with the leading
-'pod' axis (DESIGN.md §3: pods are the federated silos). Importing this
-module never touches JAX device state — construction happens inside
-``make_production_mesh()``.
+``make_edge_mesh`` lays clients over an ``(edge, client)`` device grid for
+two-tier aggregation; ``cohort_capacity`` says how many clients one
+scheduler cohort streams through a mesh. Importing this module never
+touches JAX device state.
 """
 from __future__ import annotations
 
 import numpy as np
 
 import jax
-
-# TPU v5e hardware constants (per chip) used by the roofline analysis
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # bytes/s
-ICI_BW = 50e9                  # bytes/s per link
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devices = np.asarray(jax.devices()[:n]).reshape(shape)
-    return jax.sharding.Mesh(
-        devices, axes,
-        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def client_axes(multi_pod: bool):
-    """The federated-client mesh axes (batch / silo axes)."""
-    return ("pod", "data") if multi_pod else ("data",)
 
 
 def make_edge_mesh(n_edges: int, clients_per_edge: int = None, *,
@@ -96,16 +75,3 @@ def cohort_capacity(mesh, client_axis="clients", per_device: int = 1) -> int:
     for ax in axes:
         cap *= int(mesh.shape[ax])
     return cap
-
-
-def axis_rules(multi_pod: bool) -> dict:
-    """Logical-axis -> mesh-axis rules installed for activations."""
-    fsdp = client_axes(multi_pod)
-    return {
-        "batch": fsdp,
-        "clients": fsdp,
-        "ff": "model",
-        "vocab": "model",
-        "experts": "model",
-        "cache_seq": "model",
-    }

@@ -1,9 +1,8 @@
 """Batched serving driver: prefill a batch of prompts, then greedy-decode
 with the ring-buffer KV cache (int8-quantized with --int8-kv).
 
-On this CPU container use the reduced configs; on a real slice the same
-code path serves the full configs with the decode sharding of DESIGN.md §5
-(batch over 'data', cache sequence over 'model').
+It runs on one device and places no sharding of its own; on a CPU keep
+``--preset smoke`` (the reduced config).
 
   PYTHONPATH=src python -m repro.launch.serve --arch gemma3-12b \
       --batch 4 --prompt-len 32 --new-tokens 16 [--int8-kv]

@@ -9,8 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .sharding import shard
-
 NEG_INF = -1e9  # safe for bf16/f32 masking
 
 
@@ -143,10 +141,10 @@ def decode_attention(params, cfg, x, cache, pos, use_rope=True):
     """One-token decode: x (B, 1, d); cache {"k","v"[,"k_scale","v_scale"]}
     with k/v (B, S, KV, hd) (int8 codes + scales when cfg.kv_dtype=="int8").
     The new token's K/V are written into the cache as a ring buffer at
-    ``pos % S`` and the query attends over the full (updated) cache. The
-    cache is sequence-sharded over the 'model' mesh axis (DESIGN.md §5):
-    GSPMD partitions the contraction + softmax with psum collectives (the
-    TPU analogue of split-K decode attention).
+    ``pos % S`` and the query attends over the full (updated) cache. A
+    cache sharded over its sequence axis lets GSPMD partition the
+    contraction + softmax with psum collectives (the TPU analogue of
+    split-K decode attention).
     Returns (out (B, 1, d), new_cache)."""
     hd, H, KV = cfg.hd, cfg.n_heads, cfg.n_kv_heads
     int8 = getattr(cfg, "kv_dtype", "") == "int8"
@@ -160,9 +158,8 @@ def decode_attention(params, cfg, x, cache, pos, use_rope=True):
     slot = (pos % S).astype(jnp.int32)
 
     def write(buf, val):
-        buf = jax.lax.dynamic_update_slice(
+        return jax.lax.dynamic_update_slice(
             buf, val.astype(buf.dtype), (0, slot, 0, 0))
-        return shard(buf, "batch", "cache_seq", None, None)
 
     new_cache = dict(cache)
     if int8:
@@ -430,12 +427,11 @@ def mlp_init(key, d, ff, dtype):
 
 def mlp(params, x):
     h = jax.nn.silu(x @ params["w_gate"]) * (x @ params["w_in"])
-    h = shard(h, "batch", None, "ff")
     return h @ params["w_out"]
 
 
 # ---------------------------------------------------------------------------
-# Embedding / LM head (vocab padded to a multiple of 128; DESIGN.md §5)
+# Embedding / LM head (vocab padded to a multiple of 128)
 # ---------------------------------------------------------------------------
 
 def embedding_init(key, cfg, dtype):
@@ -463,8 +459,8 @@ def logits_fn(params, x, cfg):
 
 def chunked_softmax_xent(params, x, labels, cfg, chunk: int = 128):
     """Cross-entropy without materializing (B, S, V): scan over sequence
-    chunks (DESIGN.md §5 — a 262k-vocab * 1M-token logits tensor would be
-    ~0.5 TB/device otherwise). x: (B, S, d); labels: (B, S) int32."""
+    chunks (a 262k-vocab * 1M-token logits tensor would be ~0.5 TB/device
+    otherwise). x: (B, S, d); labels: (B, S) int32."""
     B, S, d = x.shape
     chunk = min(chunk, S)
     n_chunks = S // chunk

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 
 from . import layers as L
 from . import transformer as T
-from .sharding import shard
 from ..configs.base import ArchConfig
 
 
@@ -74,7 +73,6 @@ def build_model(cfg: ArchConfig) -> Model:
     def _embed_inputs(params, batch):
         """Token embeddings (+ modality fusion). Returns (x, enc_out, n_prefix)."""
         x = L.embed(params["embedding"], batch["tokens"]).astype(dtype)
-        x = shard(x, "batch", None, None)
         enc_out, n_prefix = None, 0
         if cfg.family == "vlm":
             patches = batch["patches"].astype(dtype)
@@ -132,7 +130,6 @@ def build_model(cfg: ArchConfig) -> Model:
     def decode(params, caches, token, pos):
         """token: (B, 1) int32; pos: scalar int32 (next position index)."""
         x = L.embed(params["embedding"], token).astype(dtype)
-        x = shard(x, "batch", None, None)
         x, new_caches = T.stack_decode(_stack(params), cfg, x, caches, pos)
         x = L.rmsnorm(params["final_norm"], x)
         logits = L.logits_fn(params["embedding"], x, cfg)

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from .layers import dense_init, mlp, mlp_init
-from .sharding import shard
 
 
 def moe_init(key, cfg, dtype):
@@ -88,7 +87,6 @@ def moe_block(params, cfg, x, group: int = 0):
     combine = combine.astype(x.dtype)
 
     buf = jnp.einsum("Ntec,Ntd->Necd", dispatch, xg)              # (G, E, C, d)
-    buf = shard(buf, "batch", "experts", None, None)
 
     w = params["experts"]
     h = jax.nn.silu(jnp.einsum("Necd,edf->Necf", buf, w["w_gate"])) \

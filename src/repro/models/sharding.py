@@ -1,60 +1,30 @@
-"""Logical-axis sharding rules (MaxText-style, minimal).
+"""Weight layout: a PartitionSpec for every parameter leaf, by leaf name.
 
-Activations get ``with_sharding_constraint`` only when a rule set is
-installed (the launcher does this); unit tests on one CPU device run with no
-constraints. Parameter PartitionSpecs are assigned by leaf-name heuristics in
-``param_specs`` — the single source of truth for the weight layout described
-in DESIGN.md section 5.
+``param_specs`` assigns a 2-D FSDP x TP layout to any model's parameter
+pytree (array or ShapeDtypeStruct leaves). ``fed.trainer.state_specs``
+builds the federated state's layout from it.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 from jax.sharding import PartitionSpec as P
 
-# installed by the launcher; maps logical axis name -> mesh axis (or tuple)
-_RULES: Optional[dict] = None
-
-
-def install_rules(rules: Optional[dict]) -> None:
-    global _RULES
-    _RULES = rules
-
-
-def get_rules() -> Optional[dict]:
-    return _RULES
-
-
-def logical_to_spec(*logical_axes) -> P:
-    assert _RULES is not None
-    return P(*[_RULES.get(a) if a is not None else None for a in logical_axes])
-
-
-def shard(x, *logical_axes):
-    """with_sharding_constraint by logical axis names; no-op without rules."""
-    if _RULES is None:
-        return x
-    spec = logical_to_spec(*logical_axes)
-    return jax.lax.with_sharding_constraint(x, spec)
-
-
 # ---------------------------------------------------------------------------
-# Parameter layout: 2-D FSDP x TP weight sharding (DESIGN.md §5).
+# Parameter layout: 2-D FSDP x TP weight sharding.
 #
 #   expert stacks (E, a, b)  -> (tp on E, fsdp on a, None)   expert parallel
 #   embed/lm_head (V, d)     -> (tp, fsdp)                   vocab + FSDP
 #   any other >=2-D weight   -> (..., fsdp on dim[-2], tp on dim[-1])
-#   1-D / norms / biases / small dims -> replicated
+#   1-D / norms / biases     -> replicated
 #
-# ``fsdp`` is ('pod','data') (or ('data',) single-pod): parameters are fully
-# sharded for storage and all-gathered at use (ZeRO-3 semantics under GSPMD);
-# ``tp`` = 'model'. Every assigned config's d_model/d_ff/padded-vocab divides
-# both factors (checked: divisibility guard falls back to replication).
+# ``fsdp`` names the client/data mesh axes (e.g. ('data',)): parameters are
+# fully sharded for storage and all-gathered at use (ZeRO-3 semantics under
+# GSPMD); ``tp`` = 'model'. A dim is sharded only when the axis size
+# divides it; otherwise that dim stays replicated.
 # ---------------------------------------------------------------------------
 
-def _spec_for(path: str, shape, fsdp, fsdp_size: int, tp, tp_size: int,
-              attn_mode: str = "sharded", mlp_mode: str = "generic") -> P:
+def _spec_for(path: str, shape, fsdp, fsdp_size: int, tp,
+              tp_size: int) -> P:
     nd = len(shape)
 
     def div(dim, size):
@@ -63,26 +33,6 @@ def _spec_for(path: str, shape, fsdp, fsdp_size: int, tp, tp_size: int,
     base = [None] * nd
     name = path.split("/")[-1]
     if nd < 2:
-        return P(*base)
-    if attn_mode == "replicated" and name.split(".")[0] in (
-            "wq", "wk", "wv", "wo"):
-        # perf variant (§Perf): attention projections replicated over 'model'
-        # — trades ~2-5% weight memory for removing the per-layer activation
-        # collectives that column-parallel attention forces when the head
-        # count does not divide the TP width.
-        if fsdp and div(nd - 2, fsdp_size):
-            base[nd - 2] = fsdp
-        return P(*base)
-    if mlp_mode == "megatron" and name.startswith(("w_out", "wo")) \
-            and "expert" not in path:
-        # §Perf: pair row-parallel w_out/wo with the column-parallel
-        # w_in/w_gate/wq..: contract over the TP-sharded hidden dim (ONE
-        # all-reduce per block) instead of resharding activations between
-        # the two matmuls.
-        if div(nd - 2, tp_size):
-            base[nd - 2] = tp
-        if div(nd - 1, fsdp_size):
-            base[nd - 1] = fsdp
         return P(*base)
     if "expert" in path and nd >= 3:
         # (E, a, b) or scan-stacked (n_blocks, E, a, b)
@@ -105,54 +55,8 @@ def _spec_for(path: str, shape, fsdp, fsdp_size: int, tp, tp_size: int,
     return P(*base)
 
 
-def _cache_spec_for(path: str, shape, batch_axes, batch_size: int,
-                    tp: str, tp_size: int) -> P:
-    """Decode-cache layout (DESIGN.md §5): KV caches shard batch over the
-    client axes and *sequence over 'model'* (split-K decode attention);
-    recurrent states shard batch + channels."""
-    name = path.split("/")[-1]
-    nd = len(shape)
-    base = [None] * nd
-
-    def div(dim, size):
-        return shape[dim] % size == 0 and shape[dim] >= size
-
-    # leading dim 0 is the scan/block stack; dim 1 is batch
-    if nd >= 2 and div(1, batch_size):
-        base[1] = batch_axes
-    if name in ("k", "v", "ek", "ev", "k_scale", "v_scale") and nd == 5:
-        if div(2, tp_size):
-            base[2] = tp                       # sequence over 'model'
-    elif name in ("shift_tm", "shift_cm") and nd == 3:
-        if div(2, tp_size):
-            base[2] = tp
-    elif name == "wkv" and nd == 5:
-        if div(4, tp_size):
-            base[4] = tp
-    elif name == "conv" and nd == 4:
-        if div(3, tp_size):
-            base[3] = tp
-    elif name == "ssm" and nd == 4:
-        if div(2, tp_size):
-            base[2] = tp
-    return P(*base)
-
-
-def cache_specs(cache_shapes, batch_axes, batch_size: int,
-                tp: str = "model", tp_size: int = 16):
-    batch_axes = tuple(batch_axes) if not isinstance(batch_axes, str) else batch_axes
-    flat = jax.tree_util.tree_flatten_with_path(cache_shapes)
-    specs = []
-    for path, leaf in flat[0]:
-        pstr = "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        specs.append(_cache_spec_for(pstr, leaf.shape, batch_axes, batch_size,
-                                     tp, tp_size))
-    return jax.tree_util.tree_unflatten(flat[1], specs)
-
-
 def param_specs(params, fsdp=("data",), fsdp_size: int = 16,
-                tp: str = "model", tp_size: int = 16,
-                attn_mode: str = "sharded", mlp_mode: str = "generic"):
+                tp: str = "model", tp_size: int = 16):
     """Build a PartitionSpec pytree matching ``params`` (array or
     ShapeDtypeStruct leaves) using the layout conventions above."""
     fsdp = tuple(fsdp) if not isinstance(fsdp, str) else fsdp
@@ -160,6 +64,5 @@ def param_specs(params, fsdp=("data",), fsdp_size: int = 16,
     specs = []
     for path, leaf in flat[0]:
         pstr = "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        specs.append(_spec_for(pstr, leaf.shape, fsdp, fsdp_size, tp, tp_size,
-                               attn_mode, mlp_mode))
+        specs.append(_spec_for(pstr, leaf.shape, fsdp, fsdp_size, tp, tp_size))
     return jax.tree_util.tree_unflatten(flat[1], specs)

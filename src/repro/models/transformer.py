@@ -33,7 +33,6 @@ from . import layers as L
 from . import mamba as M
 from . import moe as MOE
 from . import rwkv as R
-from .sharding import shard
 
 
 # ---------------------------------------------------------------------------
@@ -114,12 +113,9 @@ def layer_forward(kind, params, cfg, x, enc_out=None, want_cache=False):
             if getattr(cfg, "kv_dtype", "") == "int8":
                 kq, ks = L.kv_quantize(k)
                 vq, vs = L.kv_quantize(v)
-                cache = {"k": shard(kq, "batch", "cache_seq", None, None),
-                         "v": shard(vq, "batch", "cache_seq", None, None),
-                         "k_scale": ks, "v_scale": vs}
+                cache = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
             else:
-                cache = {"k": shard(k, "batch", "cache_seq", None, None),
-                         "v": shard(v, "batch", "cache_seq", None, None)}
+                cache = {"k": k, "v": v}
         if kind == "attn_moe":
             h, aux = MOE.moe_block(params["moe"], cfg, L.rmsnorm(params["norm2"], x))
         else:
@@ -146,9 +142,7 @@ def layer_forward(kind, params, cfg, x, enc_out=None, want_cache=False):
             kv_x=enc_out, causal=False, use_rope=False)
         x = x + h
         if want_cache:
-            cache = {"k": shard(k, "batch", "cache_seq", None, None),
-                     "v": shard(v, "batch", "cache_seq", None, None),
-                     "ek": ek, "ev": ev}
+            cache = {"k": k, "v": v, "ek": ek, "ev": ev}
         h = L.mlp(params["mlp"], L.rmsnorm(params["norm2"], x))
         return x + h, cache, aux, None
     raise ValueError(kind)

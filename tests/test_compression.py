@@ -11,12 +11,21 @@ from _hypothesis_compat import given, settings, st
 from repro.core import compression as C
 
 
-def _mc_moments(comp, x, n=400, seed=0):
+def _mc_moments(comp, x, n=400, seed=0, chunk=50_000):
+    """Sample mean of ``comp.apply(k, x)`` and of its squared error over
+    ``n`` keys, drawn ``chunk`` at a time so a large ``n`` stays small in
+    memory."""
+    chunk = min(n, chunk)
+    assert n % chunk == 0
     keys = jax.random.split(jax.random.PRNGKey(seed), n)
-    outs = jax.vmap(lambda k: comp.apply(k, x))(keys)
-    mean = jnp.mean(outs, axis=0)
-    var = jnp.mean(jnp.sum((outs - x[None]) ** 2, axis=tuple(range(1, outs.ndim))))
-    return mean, var
+
+    def sums(ks):
+        outs = jax.vmap(lambda k: comp.apply(k, x))(ks)
+        return jnp.sum(outs, axis=0), jnp.sum((outs - x[None]) ** 2)
+
+    total, sq_err = jax.lax.map(sums, keys.reshape(n // chunk, chunk,
+                                                   *keys.shape[1:]))
+    return jnp.sum(total, axis=0) / n, jnp.sum(sq_err) / n
 
 
 @settings(max_examples=10, deadline=None)
@@ -41,7 +50,11 @@ def test_block_quant_unbiased_and_bounded(dim, bits, seed):
 def test_rand_k_unbiased_and_bounded(frac, seed):
     x = jax.random.normal(jax.random.PRNGKey(seed), (48,))
     comp = C.rand_k(frac)
-    mean, var = _mc_moments(comp, x, n=800, seed=seed)
+    # 10**6 draws: at fraction 0.99999, which hypothesis draws often, a
+    # coordinate drops once in 10**5 draws; the squared error's estimate
+    # sits inside 1.4 omega ||x||^2 only with hundreds of drops (about
+    # 480 here; 8,000 draws gave 4 and failed 29 seeds in 100)
+    mean, var = _mc_moments(comp, x, n=10**6, seed=seed)
     sq = float(jnp.sum(x ** 2))
     assert float(jnp.max(jnp.abs(mean - x))) < 0.3 * float(jnp.max(jnp.abs(x))) + 1e-4
     assert float(var) <= comp.omega * sq * 1.4 + 1e-8

@@ -94,13 +94,6 @@ def test_server_cv_equals_mean_of_client_cvs():
                                    rtol=2e-3, atol=2e-3)
 
 
-def test_choose_client_layout():
-    assert FT.choose_client_layout(14e9, multi_pod=True) == (32, "physical")
-    assert FT.choose_client_layout(14e9, multi_pod=False) == (16, "physical")
-    assert FT.choose_client_layout(33e9, multi_pod=True) == (4, "logical")
-    assert FT.choose_client_layout(400e9, multi_pod=False) == (2, "logical")
-
-
 @pytest.mark.slow
 def test_no_cv_mode_trains_and_drops_state():
     """use_cv=False (Theorem 1's alpha=0 regime): no V/V_i state, loss

@@ -127,12 +127,6 @@ def test_decode_consistency_attention(models, aid):
                                rtol=5e-3, atol=5e-3)
 
 
-def test_long_500k_eligibility_flags():
-    """DESIGN.md long_500k policy is encoded in config metadata."""
-    eligible = {aid for aid in C.ARCH_IDS if C.get(aid).is_subquadratic}
-    assert eligible == {"rwkv6-3b", "jamba-1.5-large-398b", "gemma3-12b"}
-
-
 def test_vocab_padding_multiple_of_128():
     for aid in C.ARCH_IDS:
         cfg = C.get(aid)
