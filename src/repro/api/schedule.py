@@ -12,7 +12,9 @@ from __future__ import annotations
 
 from typing import Sequence, Union
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 Schedule = Union[callable, Sequence, float]
 
@@ -21,8 +23,21 @@ def resolve_schedule(gammas: Schedule, n_rounds: int) -> jnp.ndarray:
     """Materialize a step-size schedule as a float32 array of shape
     ``(n_rounds,)`` — one SCALAR gamma per round, validated eagerly.
 
-    * callable: evaluated at t = 1..n_rounds (the paper's 1-indexed
-      gamma_t convention, matching the legacy ``gammas(t + 1)`` call sites);
+    * callable: gamma_t at t = 1..n_rounds (the paper's 1-indexed
+      convention, matching the legacy ``gammas(t + 1)`` call sites). It is
+      first called ONCE, eagerly, on a host NumPy vector of the round
+      indices ``np.arange(1, n_rounds + 1)``, so ``beta + t`` stays float64
+      host arithmetic exactly as with a Python int ``t``, and each
+      primitive runs unfused as in a scalar call. That vector is kept only
+      if it has shape ``(n_rounds,)`` and scalar calls at ``t = 1`` and
+      ``t = n_rounds`` return 0-d values with the same float32 bits as its
+      ends. Otherwise (the vector call raised, e.g. a Python branch on
+      ``t`` or ``math.sqrt``; the shape or an end differs) the callable is
+      evaluated per round, one scalar call each. Records the
+      ``jax.monitoring`` event ``/fedmm/schedule/vectorized`` or
+      ``/fedmm/schedule/per_round``. The probes see only the ends: a
+      callable whose vector and scalar values agree there and differ only
+      inside is taken at its vector values;
     * sequence/array: the first ``n_rounds`` entries (must be long enough);
     * python scalar: a constant schedule.
 
@@ -34,6 +49,11 @@ def resolve_schedule(gammas: Schedule, n_rounds: int) -> jnp.ndarray:
     the shapes are still static and the error can name the problem.
     """
     if callable(gammas):
+        vals = _vectorized(gammas, n_rounds)
+        if vals is not None:
+            jax.monitoring.record_event("/fedmm/schedule/vectorized")
+            return vals
+        jax.monitoring.record_event("/fedmm/schedule/per_round")
         vals = [jnp.asarray(gammas(t + 1), jnp.float32)
                 for t in range(n_rounds)]
         bad = [v.shape for v in vals if v.ndim != 0]
@@ -57,6 +77,27 @@ def resolve_schedule(gammas: Schedule, n_rounds: int) -> jnp.ndarray:
             f"indexing it by round under jit would silently clamp to the "
             f"last entry instead of raising")
     return arr[:n_rounds]
+
+
+def _vectorized(gamma, n_rounds: int) -> jnp.ndarray | None:
+    """``gamma`` at rounds 1..n_rounds from one call on a host vector, or
+    None where that call raises or disagrees with scalar calls at the
+    ends (``resolve_schedule``'s contract)."""
+    if n_rounds < 1:
+        return None
+    try:
+        vals = jnp.asarray(gamma(np.arange(1, n_rounds + 1)), jnp.float32)
+        if vals.shape != (n_rounds,):
+            return None
+        ends = [jnp.asarray(gamma(t), jnp.float32) for t in (1, n_rounds)]
+    except Exception:  # the callable is not vectorisable; go per round
+        return None
+    if any(e.ndim != 0 for e in ends):
+        return None
+    host, first, last = jax.device_get((vals, *ends))
+    got = host[[0, -1]].view(np.uint32)
+    want = np.array([first, last], np.float32).view(np.uint32)
+    return vals if np.array_equal(got, want) else None
 
 
 def decaying_stepsize(beta: float):

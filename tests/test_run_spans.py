@@ -5,11 +5,15 @@ Contracts pinned here:
     once per call through ``jax.monitoring``, also under the key-trace
     audit, and its five child phases add up to no more than the call;
   * a span whose body raises still records its duration;
+  * a federated ``api.run`` given a callable schedule resolves it in one
+    vector call (``/fedmm/schedule/vectorized``), and runs the trajectory
+    it runs on the array the per-round resolution gives, bit for bit;
   * the round's device phases carry their ``fedmm.*`` named scopes into
     the lowered program's op metadata, for both client modes.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import api
@@ -71,6 +75,45 @@ def test_run_records_each_span_once_per_call(audit_keys):
     for call in range(2):
         children = sum(d.seen[f"/fedmm/run/{p}"][call] for p in PHASES)
         assert 0.0 < children <= d.seen["/fedmm/run"][call]
+
+
+class _Events:
+    """Collects the ``/fedmm/schedule/*`` events recorded while active."""
+
+    def __init__(self):
+        self.seen = []
+
+    def __call__(self, name, **_):
+        if name.startswith("/fedmm/schedule/"):
+            self.seen.append(name)
+
+    def __enter__(self):
+        jax.monitoring.register_event_listener(self)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_listener(self)
+
+
+def test_run_resolves_callable_schedule_in_one_vector_call():
+    zs, s0, problem = _problem()
+    gamma = api.decaying_stepsize(0.3)
+
+    def run(schedule):
+        st, hist = api.run(problem, s0, lambda t, k: zs, schedule,
+                           spec=_spec(), key=KEY, n_rounds=3,
+                           eval_batch=zs[0])
+        return jax.device_get((st, hist))
+
+    with _Events() as ev:
+        got = run(gamma)
+        # ``int`` of a vector raises: the same gammas, resolved per round
+        per_round = api.resolve_schedule(lambda t: gamma(int(t)), 3)
+    assert ev.seen == ["/fedmm/schedule/vectorized",
+                       "/fedmm/schedule/per_round"]
+    want = run(per_round)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_span_records_when_its_body_raises():
